@@ -608,6 +608,9 @@ if __name__ == "__main__":
                          "sync loop vs the injected-offset clock model "
                          "(writes results/BENCH_clocksync.json)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.clocksync:
         clocksync(quick=args.quick)
     elif args.groups:

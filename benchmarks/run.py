@@ -126,6 +126,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.quick and args.full:
         ap.error("--quick and --full are mutually exclusive")
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     figs.DEFAULT_TIER = args.tier
     quick = not args.full
     names = list(ALL) if not args.only else args.only.split(",")

@@ -1,0 +1,222 @@
+#!/usr/bin/env python3
+"""Smoke run of the vectorized Nezha engine on one TPU chip.
+
+    python chip_smoke.py
+
+Drives `run_scenario` through the fused device tiers at a deployment's
+rate and holds every run to the host-only numpy tier: the same committed
+log (packed uids in log order), the same result counts, and green trace
+invariants.  One process, no subprocess; JAX runs on the TPU or the
+script exits non-zero before printing any result.
+
+Phases:
+  (a) device check: platform, kind and count of JAX's devices;
+  (b) jit tier, the cataloged intra-zone deployment (f=1, 2 proxies, 10
+      open-loop clients) at 20K req/s per client -- 200K req/s, the top of
+      the Fig 14/15 rate sweep -- for 0.5 s simulated, up to 16 epochs
+      per dispatch (retry closure cuts the K-scan windows to 4 here);
+  (c) the same run on the pallas tier, its kernels compiled; the compiled
+      K-scan program must hold a `tpu_custom_call`;
+  (d) leader-crash at its cataloged size on the jit tier: recovery and the
+      fault-operand step program.
+
+Each phase prints one JSON line: committed count, wall and compile
+seconds, the programs compiled with their pow2 batch buckets, and whether
+the float64 outputs (log deadlines, client commit times) matched numpy bit
+for bit, with their largest difference in seconds (on the TPU float64 is a
+pair of float32 words, so they need not; the committed log must).  The
+last line is ``{"ok": true, "device": {...}}``.
+"""
+from __future__ import annotations
+
+import dataclasses
+import json
+import logging
+import sys
+import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parent
+
+
+def device_check() -> dict:
+    import jax
+
+    devices = jax.devices()
+    info = {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+    print("device", json.dumps(info), flush=True)
+    if info["platform"] != "tpu":
+        sys.exit(f"chip_smoke: needs a TPU; JAX's devices are {info}")
+    return info
+
+
+class CompileLog(logging.Handler):
+    """Backend compiles in this process: program name, shapes, seconds.
+
+    JAX logs each lowering's argument shapes (at DEBUG) just before the
+    backend compile, whose duration it reports to monitoring listeners.
+    """
+
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        import jax
+        from jax._src.dispatch import BACKEND_COMPILE_EVENT
+
+        self._event = BACKEND_COMPILE_EVENT
+        self._avals: dict = {}
+        self.rows: list = []
+        log = logging.getLogger("jax._src.interpreters.pxla")
+        log.setLevel(logging.DEBUG)
+        log.propagate = False
+        log.addHandler(self)
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+
+    def emit(self, record):
+        if str(record.msg).startswith("Compiling %s with global shapes"):
+            self._avals[record.args[0]] = record.args[1]
+
+    def _on_event(self, event, secs, **kw):
+        if event == self._event:
+            name = kw.get("fun_name", "?")
+            self.rows.append((name, self._avals.pop(name, None), secs))
+
+    def take(self) -> list:
+        rows, self.rows = self.rows, []
+        return rows
+
+
+def program_rows(rows) -> list:
+    """The engine's epoch programs among `rows`, with their buckets."""
+    out = []
+    for name, avals, secs in rows:
+        if name not in ("jit(step)", "jit(scan_fn)") or avals is None:
+            continue
+        # step: first 2-D operand is owd_pr [n_pad, R];
+        # scan_fn: first 2-D operand is t [K, n_pad]
+        shape = next(a.shape for a in avals if len(a.shape) == 2)
+        row = {"program": name, "seconds": secs,
+               "bucket": shape[0] if name == "jit(step)" else shape[1]}
+        if name == "jit(scan_fn)":
+            row["K"] = shape[0]
+        out.append(row)
+    return out
+
+
+def counts(result) -> dict:
+    return {k: v for k, v in result.as_dict().items()
+            if not isinstance(v, (float, str))}
+
+
+def max_abs_diff(a, b) -> float:
+    """Largest |a - b| over the finite pairs; equal infinities count 0."""
+    import numpy as np
+
+    fin = np.isfinite(a) & np.isfinite(b)
+    if not np.array_equal(a[~fin], b[~fin]):
+        return float("inf")
+    return float(np.max(np.abs(a[fin] - b[fin]), initial=0.0))
+
+
+def run_phase(label: str, tier: str, scenario, compiles: CompileLog):
+    import numpy as np
+
+    from repro.sim.scenario import run_scenario_on_cluster
+    from repro.sim.trace import (CommitTrace, assert_trace_ok,
+                                 check_adversarial)
+
+    def run(t):
+        result, cluster = run_scenario_on_cluster(
+            "nezha-vectorized", scenario, tier=t)
+        trace = CommitTrace.from_cluster(cluster)
+        assert_trace_ok(trace)
+        result.invariant_violations = len(check_adversarial(trace))
+        return result, trace, cluster
+
+    compiles.take()
+    t0 = time.perf_counter()
+    res, trace, cluster = run(tier)
+    wall = time.perf_counter() - t0
+    rows = compiles.take()
+    ref, ref_trace, _ = run("numpy")
+    if not np.array_equal(trace.log_uids, ref_trace.log_uids):
+        raise RuntimeError(f"{label}: committed log differs from numpy")
+    if counts(res) != counts(ref):
+        raise RuntimeError(f"{label}: counts {counts(res)} != numpy "
+                           f"{counts(ref)}")
+    if res.committed == 0:
+        raise RuntimeError(f"{label}: nothing committed")
+    f64_equal = bool(
+        np.array_equal(trace.log["deadline"], ref_trace.log["deadline"])
+        and np.array_equal(trace.commits["t"], ref_trace.commits["t"]))
+    # the logs are equal in order, so the deadlines pair up; the client
+    # deliveries pair up where they come in the same order
+    deliveries_equal = np.array_equal(trace.commit_uids, ref_trace.commit_uids)
+    programs = program_rows(rows)
+    report = {
+        "phase": label, "tier": tier, "scenario": scenario.name,
+        "n_requests": res.n_requests, "committed": res.committed,
+        "view_changes": res.view_changes, "epochs": res.epochs,
+        "log_equal_numpy": True, "counts_equal_numpy": True,
+        "trace_ok": True, "f64_bits_equal_numpy": f64_equal,
+        "deadline_max_abs_diff_s": max_abs_diff(trace.log["deadline"],
+                                                ref_trace.log["deadline"]),
+        "commit_t_max_abs_diff_s": (
+            max_abs_diff(trace.commits["t"], ref_trace.commits["t"])
+            if deliveries_equal else None),
+        "wall_s": wall, "compile_s": sum(s for _, _, s in rows),
+        "programs": programs,
+        "buckets": sorted({p["bucket"] for p in programs}),
+    }
+    return report, cluster
+
+
+def pallas_custom_call(cluster, programs) -> bool:
+    """Whether the pallas tier's compiled K-scan program calls the kernels,
+    compiled at the window length and bucket the run used."""
+    import jax
+
+    from repro.analysis.lint.trace_safety import _fused_scan_args
+
+    cfg = cluster.cfg
+    scan = cluster.engine.tier.epoch_scan(
+        cfg.f, use_kcls=bool(getattr(cfg, "commutative", False)),
+        use_cap=float(getattr(cfg, "deadline_cap", 0.0) or 0.0) > 0.0)
+    used = next(p for p in programs if p["program"] == "jit(scan_fn)")
+    args = _fused_scan_args(used["K"], used["bucket"], cluster.engine.n,
+                            window=cfg.dom.window)
+    with jax.enable_x64(True):
+        text = scan.lower(**args).compile().as_text()
+    return "tpu_custom_call" in text
+
+
+def main() -> None:
+    sys.path.insert(0, str(ROOT / "src"))
+    from repro.compile_cache import enable_compile_cache
+    from repro.sim.scenario import get_scenario
+
+    info = device_check()
+    print("compile_cache", enable_compile_cache(), flush=True)
+    compiles = CompileLog()
+    base = get_scenario("intra-zone")
+    at_scale = dataclasses.replace(
+        base, name="intra-zone-200k",
+        workload=dataclasses.replace(base.workload, rate_per_client=20000.0,
+                                     duration=0.5),
+        overrides={**base.overrides, "epochs_per_dispatch": 16})
+    report, _ = run_phase("b", "jit", at_scale, compiles)
+    print(json.dumps(report), flush=True)
+    report, cluster = run_phase("c", "pallas", at_scale, compiles)
+    print(json.dumps(report), flush=True)
+    has_kernel = pallas_custom_call(cluster, report["programs"])
+    print(json.dumps({"phase": "c", "tpu_custom_call": has_kernel}),
+          flush=True)
+    if not has_kernel:
+        raise RuntimeError("c: the pallas program holds no tpu_custom_call")
+    report, _ = run_phase("d", "jit", get_scenario("leader-crash"), compiles)
+    print(json.dumps(report), flush=True)
+    print(json.dumps({"ok": True, "device": info}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

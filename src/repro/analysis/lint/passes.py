@@ -137,6 +137,13 @@ class ModuleIndex(ast.NodeVisitor):
             info.start = min(info.start, dec.lineno)
         if node.name.endswith("_traced"):
             info.traced = True
+        # `enable_x64(False)` scopes switch 64-bit types OFF (the Pallas
+        # wrappers trace their int32 kernels that way): not an x64 entry
+        x64_off = {id(c.func) for c in ast.walk(node)
+                   if isinstance(c, ast.Call)
+                   and _terminal_name(c.func) == "enable_x64"
+                   and c.args and isinstance(c.args[0], ast.Constant)
+                   and c.args[0].value is False}
         for n in ast.walk(node):
             if isinstance(n, ast.Call):
                 callee = _terminal_name(n.func)
@@ -148,8 +155,9 @@ class ModuleIndex(ast.NodeVisitor):
                     ref = _terminal_name(a)
                     if ref:
                         info.calls.add(ref)
-            if _terminal_name(n) == "enable_x64" or (
-                    isinstance(n, ast.Name) and n.id == "enable_x64"):
+            if id(n) not in x64_off and (
+                    _terminal_name(n) == "enable_x64"
+                    or (isinstance(n, ast.Name) and n.id == "enable_x64")):
                 info.has_x64 = True
         self.functions[qual] = info
         self._stack.append(node.name)

@@ -186,7 +186,6 @@ def check_fused_step(f: int = 1, n: int = 8) -> list[Finding]:
     across their specialization axes and assert the float64-end-to-end +
     no-callback contract on each jaxpr."""
     import jax
-    from jax.experimental import enable_x64
 
     from repro.core.engine import JitTier
 
@@ -210,7 +209,7 @@ def check_fused_step(f: int = 1, n: int = 8) -> list[Finding]:
                  + (f", {'/'.join(fault)}" if fault else "") + ")")
         step = tier.epoch_step(f, use_kcls=use_kcls, use_cap=use_cap)
         kw = _fused_step_args(n, r, **fault)
-        with enable_x64():
+        with jax.enable_x64(True):
             jaxpr = jax.make_jaxpr(step)(**kw)
         findings.extend(_trace_contract(jaxpr, label, ENGINE_PATH))
     # the K-epoch scan shares the epoch body but stages it under lax.scan
@@ -220,7 +219,7 @@ def check_fused_step(f: int = 1, n: int = 8) -> list[Finding]:
         label = f"_build_fused_scan(K=4, use_kcls={use_kcls})"
         scan = tier.epoch_scan(f, use_kcls=use_kcls)
         kw = _fused_scan_args(4, n, r)
-        with enable_x64():
+        with jax.enable_x64(True):
             jaxpr = jax.make_jaxpr(scan)(**kw)
         findings.extend(_trace_contract(jaxpr, label, ENGINE_PATH))
     return findings
@@ -232,7 +231,6 @@ def check_kernel_wrappers(n: int = 8, r: int = 3) -> list[Finding]:
     any sub-f64 float op is a regression toward the old span-relative-f32
     keys and their tie window."""
     import jax
-    from jax.experimental import enable_x64
 
     findings: list[Finding] = []
     try:
@@ -241,7 +239,7 @@ def check_kernel_wrappers(n: int = 8, r: int = 3) -> list[Finding]:
         rng = np.random.default_rng(0)
         d = rng.uniform(0.0, 1.0, n)
         a = rng.uniform(0.0, 1.0, (n, r))
-        with enable_x64():
+        with jax.enable_x64(True):
             traces = {
                 "dom_admit_traced":
                     jax.make_jaxpr(

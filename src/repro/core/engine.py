@@ -33,7 +33,7 @@ property-test oracle):
   pallas   fused epochs like jit, but admission runs in the
            `repro.kernels.dom_admit` bitonic-event-sort + prefix-max kernel
            and release ordering in the `repro.kernels.ops.dom_release`
-           bitonic kernel (interpret mode off-TPU). Event times are compared
+           bitonic kernel (interpreted on the CPU). Event times are compared
            as exact two-word int32 keys (repro.kernels.timekeys), so kernel
            sort order equals the float64 tiers' order unconditionally --
            ties included; there is no precision caveat.
@@ -43,7 +43,7 @@ replace the Stamp/Dom/Commit stages with one `FusedEpochStage` whose body is
 a single jitted program -- ring-pool OWD fold + sliding-percentile deadline
 bounding, the mean-reply fetch estimate, watermark admission, release
 times, and the quorum arithmetic of `classify_commits` as jnp ops over the
-pow2-padded batch, traced under float64 (`jax.experimental.enable_x64`) so
+pow2-padded batch, traced under float64 (`jax.enable_x64`) so
 the release/commit boundary no longer needs the host-side float64 recompute
 the old per-stage jit path did. The two formerly host-owned per-epoch
 scalars -- the sliding-pool percentile ``bound`` and the mean-reply
@@ -292,14 +292,14 @@ class JitTier(ComputeTier):
     fused = True
 
     def release_schedule(self, deadlines, arrivals):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         from repro.core.vectorized import _watermark_schedule_jit
 
         # Traced under x64 so admission AND release are float64 end to end;
         # no host-side boundary recompute needed.
-        with enable_x64():
+        with jax.enable_x64(True):
             adm, rel = _watermark_schedule_jit(
                 jnp.asarray(np.asarray(deadlines, np.float64)),
                 jnp.asarray(np.asarray(arrivals, np.float64)))
@@ -321,8 +321,8 @@ class PallasTier(JitTier):
     Admission runs in `repro.kernels.dom_admit` (bitonic event sort fused
     with the watermark prefix-max, one grid program per receiver); the
     release/deadline ordering runs in the `repro.kernels.ops.dom_release`
-    bitonic kernel. Interpret mode off-TPU. Both compare times as exact
-    two-word int32 keys (repro.kernels.timekeys), so the kernel order
+    bitonic kernel; on the CPU they run interpreted. Both compare times as
+    exact two-word int32 keys (repro.kernels.timekeys), so the kernel order
     equals the float64 tiers' order unconditionally -- ties included.
     """
 
@@ -1127,7 +1127,7 @@ class FusedEpochStage(Stage):
     name = "fused"
 
     def run(self, s, eng):
-        from jax.experimental import enable_x64
+        import jax
 
         cfg = eng.cfg
         N = s.t.size
@@ -1195,7 +1195,7 @@ class FusedEpochStage(Stage):
         cap = float(getattr(cfg, "deadline_cap", 0.0) or 0.0)
         step = eng.tier.epoch_step(cfg.f, use_kcls=s.kcls is not None,
                                    use_cap=cap > 0.0)
-        with enable_x64():
+        with jax.enable_x64(True):
             out = step(pool, ptr, cnt, t, c2p, owd, drop, reply,
                        np.asarray(s.alive, bool), kcls, s.leader, N,
                        float(cfg.dom.percentile) / 100.0, eng.bound_margin(),
@@ -1899,7 +1899,7 @@ class DomEngine:
         device data plane runs as one `lax.scan` with a single
         end-of-window pull -- zero per-epoch device round trips.
         """
-        from jax.experimental import enable_x64
+        import jax
 
         if not self.tier.fused or self.clocks_faulty or self.pairs_faulty \
                 or self.stampers_biased or self.sync_active \
@@ -1971,7 +1971,7 @@ class DomEngine:
         scan = self.tier.epoch_scan(cfg.f, use_kcls=commutative,
                                     use_cap=cap > 0.0)
         pool, ptr, cnt = self.device_pool_state()
-        with enable_x64():
+        with jax.enable_x64(True):
             out = scan(pool, ptr, cnt, t, c2p, owd, drop, reply, kcls,
                        n_valid, alive, int(leader),
                        float(cfg.dom.percentile) / 100.0, self.bound_margin(),

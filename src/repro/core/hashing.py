@@ -22,12 +22,8 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
-try:  # JAX is always present in this repo, but keep the core importable alone.
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
-    jnp = None
+import jax
+import jax.numpy as jnp
 
 _MASK64 = np.uint64(0xFFFFFFFFFFFFFFFF)
 
@@ -193,36 +189,38 @@ def entry_hash32_np(deadline_ns: np.ndarray, client_id: np.ndarray, request_id: 
         return _murmur32_np(d ^ ((c * np.uint32(0x01000193)) & _MASK32) ^ r)
 
 
-if jnp is not None:
+def _murmur32_jnp(x):
+    x = x.astype(jnp.uint32)
+    x = x ^ (x >> jnp.uint32(16))
+    x = x * jnp.uint32(0x85EBCA6B)
+    x = x ^ (x >> jnp.uint32(13))
+    x = x * jnp.uint32(0xC2B2AE35)
+    return x ^ (x >> jnp.uint32(16))
 
-    def _murmur32_jnp(x):
-        x = x.astype(jnp.uint32)
-        x = x ^ (x >> jnp.uint32(16))
-        x = x * jnp.uint32(0x85EBCA6B)
-        x = x ^ (x >> jnp.uint32(13))
-        x = x * jnp.uint32(0xC2B2AE35)
-        return x ^ (x >> jnp.uint32(16))
 
-    def entry_hash_jnp(deadline_ns, client_id, request_id):
-        """Vectorized h(request); bit-identical to entry_hash32_np."""
-        d = _murmur32_jnp(jnp.asarray(deadline_ns).astype(jnp.uint32))
-        c = _murmur32_jnp(jnp.asarray(client_id).astype(jnp.uint32) ^ jnp.uint32(0xA5A5A5A5))
-        r = _murmur32_jnp(jnp.asarray(request_id).astype(jnp.uint32) ^ jnp.uint32(0x5A5A5A5A))
-        return _murmur32_jnp(d ^ (c * jnp.uint32(0x01000193)) ^ r)
+def entry_hash_jnp(deadline_ns, client_id, request_id):
+    """Vectorized h(request); bit-identical to entry_hash32_np."""
+    d = _murmur32_jnp(jnp.asarray(deadline_ns).astype(jnp.uint32))
+    c = _murmur32_jnp(jnp.asarray(client_id).astype(jnp.uint32) ^ jnp.uint32(0xA5A5A5A5))
+    r = _murmur32_jnp(jnp.asarray(request_id).astype(jnp.uint32) ^ jnp.uint32(0x5A5A5A5A))
+    return _murmur32_jnp(d ^ (c * jnp.uint32(0x01000193)) ^ r)
 
-    def fold_hashes_jnp(hashes):
-        """XOR-fold -> H_n over a whole set."""
-        h = jnp.asarray(hashes).astype(jnp.uint32)
-        return jax.lax.reduce(h.ravel(), jnp.uint32(0), jax.lax.bitwise_xor, (0,))
 
-    def prefix_hashes_jnp(hashes):
-        """hash_i for every prefix (what the i-th fast-reply carries)."""
-        return jax.lax.associative_scan(jnp.bitwise_xor, jnp.asarray(hashes).astype(jnp.uint32))
+def fold_hashes_jnp(hashes):
+    """XOR-fold -> H_n over a whole set."""
+    h = jnp.asarray(hashes).astype(jnp.uint32)
+    return jax.lax.reduce(h.ravel(), jnp.uint32(0), jax.lax.bitwise_xor, (0,))
 
-    def crash_vector_hash_jnp(cv):
-        cv = jnp.asarray(cv).astype(jnp.uint32)
-        idx = jnp.arange(cv.shape[-1], dtype=jnp.uint32)
-        return fold_hashes_jnp(_murmur32_jnp(cv ^ _murmur32_jnp(idx)))
+
+def prefix_hashes_jnp(hashes):
+    """hash_i for every prefix (what the i-th fast-reply carries)."""
+    return jax.lax.associative_scan(jnp.bitwise_xor, jnp.asarray(hashes).astype(jnp.uint32))
+
+
+def crash_vector_hash_jnp(cv):
+    cv = jnp.asarray(cv).astype(jnp.uint32)
+    idx = jnp.arange(cv.shape[-1], dtype=jnp.uint32)
+    return fold_hashes_jnp(_murmur32_jnp(cv ^ _murmur32_jnp(idx)))
 
 
 __all__ = [

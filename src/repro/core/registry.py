@@ -13,8 +13,8 @@ Registered names
                      (numpy compute tier; pass VectorizedConfig(tier=...)
                      or use the tier-pinned names below)
   nezha-vectorized-jit      same engine, fused-jit DOM tier
-  nezha-vectorized-pallas   same engine, Pallas dom_release kernel tier
-                            (interpret mode off-TPU)
+  nezha-vectorized-pallas   same engine, Pallas dom_admit/dom_release
+                            kernel tier (TPU; interpreted on the CPU)
   nezha-sharded      `ShardedNezhaCluster` -- G independent Nezha groups
                      over one key space (ShardedConfig(groups=...)); stable
                      key->group routing, cross-group multi-key ops in

@@ -434,7 +434,7 @@ class ShardedNezhaCluster(Cluster):
         lane (no host rng draws, no bound update, outputs discarded), so
         the vmapped program's shape key is (G, pow2 bucket) and the compile
         count stays bounded per TS003's G-bucket accounting."""
-        from jax.experimental import enable_x64
+        import jax
 
         cfg = self.cfg
         commutative = bool(getattr(cfg, "commutative", False))
@@ -498,7 +498,7 @@ class ShardedNezhaCluster(Cluster):
         cap = float(getattr(cfg, "deadline_cap", 0.0) or 0.0)
         eng0 = groups[0].engine
         step = self._vstep(cfg.f, use_kcls=commutative, use_cap=cap > 0.0)
-        with enable_x64():
+        with jax.enable_x64(True):
             out = step(np.stack(pools), np.asarray(ptrs), np.asarray(cnts),
                        t, c2p, owd, drop, reply, alive, kcls, lead,
                        n_valid, float(cfg.dom.percentile) / 100.0,

@@ -128,9 +128,7 @@ def dom_release_schedule(deadlines, arrivals) -> tuple:
     the kernel reference oracle -- silently got float32 admission, which
     collapses sub-microsecond deadline separations.
     """
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         return _dom_release_schedule_impl(jnp.asarray(deadlines),
                                           jnp.asarray(arrivals))
 
@@ -217,22 +215,33 @@ def dom_admit_watermark_jnp(deadlines: jnp.ndarray,
     """Traceable watermark admission: [N],[N,R] -> [N,R] bool.
 
     Same event construction as `dom_admit_watermark_np`, with the sequential
-    O(N^2) scan carry replaced by sort + cummax (O(1) carried state).  Runs
-    at whatever precision the caller traces it at -- the engine's fused
-    epoch step traces it under float64 for exact numpy-tier parity.
+    O(N^2) scan carry replaced by sort + prefix max (O(1) carried state).
+    Runs at whatever precision the caller traces it at -- the engine's
+    fused epoch step traces it under float64 for exact numpy-tier parity.
+
+    Receivers are unrolled (R = 2f+1 is static and small), not vmapped:
+    XLA:TPU compiles a batched float64 sort super-linearly in N (minutes at
+    N=2048), while R separate 1-D sorts compile in seconds.  The prefix max
+    is a log-step `associative_scan`; `lax.cummax` lowers to a float64
+    reduce-window that alone takes seconds to compile on the TPU.  Max is
+    exact, so both give the same bits.  The tie-break key and the carried
+    index are int32 (aux < 4N): a sort over emulated int64 operands
+    compiles about twice as slowly on the TPU.
     """
     d = deadlines
     N = d.shape[0]
-    idx = jnp.arange(N)
+    idx = jnp.arange(N, dtype=jnp.int32)
     contrib = jnp.where(jnp.isfinite(d), d, -jnp.inf)
     no_upd = jnp.full((N,), -jnp.inf, d.dtype)
 
     def one_receiver(ar):
         times = jnp.concatenate([ar, jnp.maximum(d, ar)])
-        cls = jnp.where(d > ar, 0, N)
+        cls = jnp.where(d > ar, 0, N).astype(jnp.int32)
         aux = jnp.concatenate([(N + idx) * 2, (cls + idx) * 2 + 1])
-        order = jnp.lexsort((aux, times))
-        runmax = jax.lax.cummax(jnp.concatenate([no_upd, contrib])[order])
+        _, _, order = jax.lax.sort(
+            (times, aux, jnp.arange(2 * N, dtype=jnp.int32)), num_keys=2)
+        runmax = jax.lax.associative_scan(
+            jnp.maximum, jnp.concatenate([no_upd, contrib])[order])
         excl = jnp.concatenate([jnp.full((1,), -jnp.inf, d.dtype),
                                 runmax[:-1]])
         is_test = order < N
@@ -241,7 +250,8 @@ def dom_admit_watermark_jnp(deadlines: jnp.ndarray,
             & jnp.isfinite(ar[jnp.minimum(m, N - 1)])
         return jnp.zeros((N,), bool).at[m].set(ok, mode="drop")
 
-    return jax.vmap(one_receiver, in_axes=1, out_axes=1)(arrivals)
+    return jnp.stack([one_receiver(arrivals[:, r])
+                      for r in range(arrivals.shape[1])], axis=1)
 
 
 @jax.jit

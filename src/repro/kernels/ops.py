@@ -1,8 +1,8 @@
 """jit'd wrappers selecting kernel vs. pure-jnp path.
 
-On TPU the Pallas kernels run compiled; this container is CPU-only so the
-default is the jnp path, with `use_pallas=True` running interpret mode
-(used by the test suite; identical numerics asserts).
+On the TPU the Pallas kernels run compiled.  On the CPU they run in the
+Pallas interpreter (the test suite's mode); `interpret_mode` makes that
+choice for every wrapper, and refuses any other backend.
 
 Time keys: the DOM kernels compare event times as exact two-word int32
 keys (repro.kernels.timekeys), so the pallas path needs no span shift, no
@@ -26,12 +26,26 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted: on the CPU yes, on the TPU no.
+
+    The kernels are written for the TPU; the CPU runs them through the
+    Pallas interpreter.  Any other backend raises instead of interpreting
+    in silence.
+    """
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run on the TPU, or interpreted "
+                           f"on the CPU; the backend is {backend!r}")
+    return backend == "cpu"
+
+
 def attention(q, k, v, *, causal=True, window=None, use_pallas=None):
     if use_pallas is None:
         use_pallas = _on_tpu()
     if use_pallas:
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      interpret=not _on_tpu())
+                                      interpret=interpret_mode())
     from repro.models.attention import flash_attention
 
     return flash_attention(q, k, v, causal=causal, window=window)
@@ -42,7 +56,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk=128, use_pallas=None):
         use_pallas = _on_tpu()
     if use_pallas:
         return ssd_scan_pallas(x, dt, A, B, C, chunk=chunk,
-                               interpret=not _on_tpu())
+                               interpret=interpret_mode())
     return _ref.ssd_scan_ref(x, dt, A, B, C)
 
 
@@ -57,7 +71,7 @@ def dom_admit_traced(deadlines, arrivals, *, use_pallas=True):
     """
     if use_pallas:
         return dom_admit_pallas(deadlines, arrivals.T,
-                                interpret=not _on_tpu()).T
+                                interpret=interpret_mode()).T
     from repro.core.vectorized import dom_admit_watermark_jnp
 
     return dom_admit_watermark_jnp(deadlines, arrivals)
@@ -68,12 +82,10 @@ def dom_admit(deadlines, arrivals, *, use_pallas=None):
 
     Off-kernel the float64 numpy watermark path is the reference; with
     `use_pallas` the bitonic event sort + prefix-max kernel runs admission
-    on-device (interpret mode off-TPU) over exact int32 time keys --
+    on-device (interpreted on the CPU) over exact int32 time keys --
     bit-identical to the numpy watermark, ties included.
     """
     import numpy as np
-
-    from jax.experimental import enable_x64
 
     if use_pallas is None:
         use_pallas = _on_tpu()
@@ -83,9 +95,9 @@ def dom_admit(deadlines, arrivals, *, use_pallas=None):
         from repro.core.vectorized import dom_admit_watermark_np
 
         return dom_admit_watermark_np(d, a)
-    with enable_x64():
+    with jax.enable_x64(True):
         adm = dom_admit_pallas(jnp.asarray(d), jnp.asarray(a.T),
-                               interpret=not _on_tpu())
+                               interpret=interpret_mode())
     return np.asarray(adm).T  # lint: allow[HS003] host-entry wrapper: one pull of the kernel result
 
 
@@ -94,7 +106,7 @@ def dom_release(deadlines, admitted, clock_now, *, use_pallas=None):
         use_pallas = _on_tpu()
     if use_pallas:
         return dom_release_pallas(deadlines, admitted, clock_now,
-                                  interpret=not _on_tpu())
+                                  interpret=interpret_mode())
     return dom_release_ref_order(deadlines, admitted, clock_now)
 
 
@@ -105,9 +117,7 @@ def dom_release_ref_order(deadlines, admitted, clock_now):
     comparison precision regardless of the caller's x64 context (jit-free,
     plain jnp ops; float32 inputs stay float32).
     """
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         deadlines = jnp.asarray(deadlines)
         released = jnp.asarray(admitted, bool) & (deadlines <= clock_now)
         keys = jnp.where(released, deadlines, jnp.inf)
@@ -131,16 +141,21 @@ def dom_deadline_order(deadlines, *, use_pallas=None):
     """
     import numpy as np
 
-    from jax.experimental import enable_x64
-
     d = np.asarray(deadlines, np.float64)
     n = d.size
     if n == 0:
         return np.zeros(0, np.int64)
-    with enable_x64():
-        order, _ = dom_release(jnp.asarray(d), jnp.ones(n, jnp.int8),
-                               jnp.asarray(np.inf), use_pallas=use_pallas)
-    return np.asarray(order, dtype=np.int64)  # lint: allow[HS003] host-entry wrapper: one pull of the kernel result
+    # pad to a pow2 bucket with never-admitted lanes (they sort behind every
+    # real one), so epochs of every size share O(log n) compiled programs
+    n_b = 1 << (n - 1).bit_length()
+    admitted = np.zeros(n_b, np.int8)
+    admitted[:n] = 1
+    with jax.enable_x64(True):
+        order, _ = dom_release(
+            jnp.asarray(np.pad(d, (0, n_b - n), constant_values=np.inf)),
+            jnp.asarray(admitted), jnp.asarray(np.inf),
+            use_pallas=use_pallas)
+    return np.asarray(order[:n], dtype=np.int64)  # lint: allow[HS003] host-entry wrapper: one pull of the kernel result
 
 
 def dom_deadline_order_traced(deadlines, *, use_pallas=True):
@@ -155,7 +170,7 @@ def dom_deadline_order_traced(deadlines, *, use_pallas=True):
         return jnp.argsort(d, stable=True)
     order, _ = dom_release_pallas(d, jnp.ones(d.shape[0], jnp.int8),
                                   jnp.full((), jnp.inf, d.dtype),
-                                  interpret=not _on_tpu())
+                                  interpret=interpret_mode())
     return order
 
 
@@ -164,7 +179,7 @@ def inchash(deadline_ns, client_id, request_id, *, use_pallas=None):
         use_pallas = _on_tpu()
     if use_pallas:
         return inchash_pallas(deadline_ns, client_id, request_id,
-                              interpret=not _on_tpu())
+                              interpret=interpret_mode())
     return _ref.inchash_ref(deadline_ns, client_id, request_id)
 
 

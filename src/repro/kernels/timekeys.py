@@ -44,22 +44,43 @@ HI_INF = 0x7FF00000
 LO_INF = I32_MIN
 
 
-def time_sort_keys(x):
+def _f32_key(v):
+    """Signed-int32 key of float32 `v`: integer order == float order."""
+    bits = jax.lax.bitcast_convert_type(v, jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(I32_MAX))
+
+
+def time_sort_keys(x, *, f32_pair: bool = False):
     """Encode float times as (hi, lo) int32 words.
 
     Lexicographic signed comparison of the pairs equals the exact IEEE
     total order of the input dtype (non-NaN).  Non-finite inputs all map
     to the +inf key ``(HI_INF, LO_INF)``.
+
+    ``f32_pair`` encodes a float64 by its float32 split instead of its
+    IEEE bits: hi = x rounded to float32, lo = the float32 remainder.  The
+    TPU stores float64 as exactly such a pair (XLA's X64 split) and cannot
+    bitcast it, so kernels compiled for the TPU use this form.  It is
+    exact for the TPU's float64 but not for IEEE doubles, whose 53-bit
+    mantissa the two float32 words cannot hold.
     """
-    if x.dtype == jnp.float64:
-        bits = jax.lax.bitcast_convert_type(x, jnp.int32)  # [..., 2] LE words
-        lo, hi = bits[..., 0], bits[..., 1]
+    if x.dtype == jnp.float64 and f32_pair:
+        hi32 = x.astype(jnp.float32)
+        lo32 = (x - hi32.astype(jnp.float64)).astype(jnp.float32)
+        # canonical +0.0 remainder, so equal values get equal keys
+        lo32 = jnp.where(lo32 == 0, jnp.float32(0), lo32)
+        hi_k, lo_k = _f32_key(hi32), _f32_key(lo32)
     else:
-        hi = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
-        lo = jnp.zeros_like(hi)
-    s = hi >> 31                                  # 0 (x >= 0) or -1 (x < 0)
-    hi_k = hi ^ (s & jnp.int32(I32_MAX))
-    lo_k = (lo ^ s) ^ jnp.int32(I32_MIN)
+        if x.dtype == jnp.float64:
+            bits = jax.lax.bitcast_convert_type(x, jnp.int32)  # LE words
+            lo, hi = bits[..., 0], bits[..., 1]
+        else:
+            hi = jax.lax.bitcast_convert_type(x.astype(jnp.float32),
+                                              jnp.int32)
+            lo = jnp.zeros_like(hi)
+        s = hi >> 31                              # 0 (x >= 0) or -1 (x < 0)
+        hi_k = hi ^ (s & jnp.int32(I32_MAX))
+        lo_k = (lo ^ s) ^ jnp.int32(I32_MIN)
     isfin = jnp.isfinite(x)
     return (jnp.where(isfin, hi_k, jnp.int32(HI_INF)),
             jnp.where(isfin, lo_k, jnp.int32(LO_INF)))

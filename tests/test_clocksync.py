@@ -62,13 +62,12 @@ def _random_round(seed: int, m: int = None):
 def test_estimator_numpy_vs_jit_bitwise(seed):
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     theta, rtt = _random_round(seed)
     safety, floor = np.float64(1.5), np.float64(200e-9)
     en, sn = estimate_offsets(theta, rtt, np, safety, floor)
     f = jax.jit(lambda t, r, s, fl: estimate_offsets(t, r, jnp, s, fl))
-    with enable_x64():
+    with jax.enable_x64(True):
         ej, sj = f(theta, rtt, safety, floor)
     np.testing.assert_array_equal(en, np.asarray(ej))
     np.testing.assert_array_equal(sn, np.asarray(sj))

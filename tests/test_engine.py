@@ -60,12 +60,12 @@ def _adversarial_instance(style, n, r, seed):
 
 def _exact_oracle_admission(d, a):
     """The retained O(N^2) scan oracle, traced in float64."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.vectorized import dom_release_schedule
 
-    with enable_x64():
+    with jax.enable_x64(True):
         adm, _ = dom_release_schedule(jnp.asarray(d, jnp.float64),
                                       jnp.asarray(a, jnp.float64))
         return np.asarray(adm)
@@ -371,7 +371,7 @@ def test_device_percentile_and_ring_pool_match_host_sliding_pool():
     `update_bound`/`_partition_percentile` pipeline EXACTLY, epoch for
     epoch, across pool sizes, duplicates, overflow, clamping, empty pools,
     and q endpoints; and the carried ring equals the host sliding pool."""
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.core.engine import _partition_percentile
 
@@ -412,7 +412,7 @@ def test_device_percentile_and_ring_pool_match_host_sliding_pool():
                 n_valid.astype(np.int64),
                 np.ones(r, bool), 0,
                 float(q) / 100.0, margin, float(clamp_d), 0.0, 0.0, 0.0)
-        with enable_x64():
+        with jax.enable_x64(True):
             out = scan(*args)
         bounds = np.asarray(out[8])
         pool_dev = np.asarray(out[9])

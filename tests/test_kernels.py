@@ -88,9 +88,8 @@ def test_dom_release_kernel_f64_duplicates_and_1ns_gaps():
     """float64 inputs with exact duplicate deadlines and 1ns separations
     straddling `now`: the int32 (hi, lo) key words preserve the full f64
     order, and equal deadlines release in index order (stable argsort)."""
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64(True):
         base = np.sort(RNG.uniform(1.0, 2.0, 32))
         d = np.repeat(base, 4) + np.tile([0.0, 0.0, 1e-9, 2e-9], 32)
         d = d[RNG.permutation(d.size)]
@@ -146,14 +145,13 @@ def test_dom_admit_kernel_realistic_owd():
     """A realistic OWD spread, fed RAW as float64 -- no span shift, no
     downcast: the kernel bitcasts the caller-precision times to exact
     int32 key words, so absolute epoch-scale inputs are handled as-is."""
-    from jax.experimental import enable_x64
 
     n = 128
     send = np.sort(RNG.uniform(0, 5e-3, n)) + np.arange(n) * 1e-6
     d = send + 120e-6
     a = send[:, None] + RNG.lognormal(np.log(60e-6), 0.6, (n, 3))
     a[RNG.random((n, 3)) < 0.02] = np.inf
-    with enable_x64():
+    with jax.enable_x64(True):
         got = dom_admit_pallas(jnp.asarray(d), jnp.asarray(a.T),
                                interpret=True)
     np.testing.assert_array_equal(np.asarray(got).T, _admit_oracle(d, a))
@@ -163,7 +161,6 @@ def test_dom_admit_kernel_sub_f32_resolution_ties():
     """Deadline/arrival gaps far below float32 resolution at the working
     magnitude: a float32 downcast would collapse them (the old design's
     documented tie window); the f64 (hi, lo) keys keep the exact order."""
-    from jax.experimental import enable_x64
 
     base = np.sort(RNG.uniform(1.0, 5.0, 64))
     d = np.repeat(base, 4) + np.tile([0.0, 1e-9, 2e-9, 3e-9], 64)
@@ -173,7 +170,7 @@ def test_dom_admit_kernel_sub_f32_resolution_ties():
     a[RNG.random(a.shape) < 0.1] = np.inf
     # the scenario is meaningful: f32 cannot represent these separations
     assert (np.float32(base[0]) == np.float32(base[0] + 1e-9))
-    with enable_x64():
+    with jax.enable_x64(True):
         got = dom_admit_pallas(jnp.asarray(d), jnp.asarray(a.T),
                                interpret=True)
     np.testing.assert_array_equal(np.asarray(got).T, _admit_oracle(d, a))
@@ -188,6 +185,30 @@ def test_dom_admit_kernel_all_dropped_receiver():
     got = np.asarray(got).T
     assert not got[:, 0].any()                  # dropped receiver admits none
     np.testing.assert_array_equal(got, _admit_oracle(d, a))
+
+
+def test_time_keys_f32_pair_is_exact_on_float32_pairs():
+    """The TPU holds a float64 as a float32 (hi, lo) pair, and compiled
+    kernels encode times by that split.  On values that are such pairs
+    (|lo| < ulp(hi)/2) the split keys give the exact total order: equal
+    keys iff equal values, lexicographic key order == value order."""
+    from repro.kernels.timekeys import time_sort_keys
+
+    hi = RNG.uniform(-1.0, 1.0, 3000).astype(np.float32)
+    lo = (RNG.uniform(-0.49, 0.49, 3000) * np.spacing(hi)).astype(np.float32)
+    x = hi.astype(np.float64) + lo.astype(np.float64)
+    x[::5] = x[1::5][: x[::5].size]             # exact duplicates
+    # neighbours 2^-40 apart: beyond float32, within the pair's reach
+    x[:64] = 0.5 + np.arange(64).astype(np.float32) * np.float32(2.0 ** -40)
+    x[-3:] = np.inf
+    with jax.enable_x64(True):
+        khi, klo = time_sort_keys(jnp.asarray(x), f32_pair=True)
+    khi, klo = np.asarray(khi), np.asarray(klo)
+    np.testing.assert_array_equal(np.lexsort((klo, khi)),
+                                  np.argsort(x, kind="stable"))
+    srt = np.argsort(x, kind="stable")
+    same_key = (khi[srt][1:] == khi[srt][:-1]) & (klo[srt][1:] == klo[srt][:-1])
+    np.testing.assert_array_equal(same_key, x[srt][1:] == x[srt][:-1])
 
 
 # ---------------------------------------------------------------------------

@@ -53,14 +53,29 @@ def test_dp002_flags_jnp_time_compute_without_x64():
 
 def test_dp002_clean_under_enable_x64():
     found = _lint_src("""
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         def schedule(deadlines, arrivals):
-            with enable_x64():
+            with jax.enable_x64(True):
                 return jnp.maximum(deadlines, arrivals[:, 0])
     """)
     assert _rules(found) == []
+
+
+def test_dp002_enable_x64_false_is_not_an_x64_entry():
+    """`jax.enable_x64(False)` switches 64-bit types off (the Pallas
+    wrappers trace their int32 kernels that way), so it must not count
+    as the x64 scope that time compute needs."""
+    found = _lint_src("""
+        import jax
+        import jax.numpy as jnp
+
+        def schedule(deadlines, arrivals):
+            with jax.enable_x64(False):
+                return jnp.maximum(deadlines, arrivals[:, 0])
+    """)
+    assert "DP002" in _rules(found)
 
 
 def test_dp002_x64_reaches_intra_module_callees():
@@ -70,13 +85,12 @@ def test_dp002_x64_reaches_intra_module_callees():
     found = _lint_src("""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         def _one_receiver(deadlines):
             return jnp.sort(deadlines)
 
         def schedule(deadlines):
-            with enable_x64():
+            with jax.enable_x64(True):
                 return jax.vmap(_one_receiver)(deadlines)
     """)
     assert _rules(found) == []

@@ -159,8 +159,8 @@ def test_watermark_admission_matches_exact_oracle(n, r, seed, grid, drop_p,
     the retained O(N^2) `dom_release_schedule` oracle on adversarial cases:
     late arrivals beyond the deadline, duplicate deadlines, inf-dropped
     arrivals, and all-dropped receivers."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.vectorized import (
         _watermark_schedule_jit,
@@ -170,7 +170,7 @@ def test_watermark_admission_matches_exact_oracle(n, r, seed, grid, drop_p,
 
     deadlines, arrivals = _adversarial_dom_instance(
         n, r, seed, grid, drop_p, late_scale, inf_deadline_p, kill_receiver)
-    with enable_x64():
+    with jax.enable_x64(True):
         want = np.asarray(dom_release_schedule(
             jnp.asarray(deadlines, jnp.float64),
             jnp.asarray(arrivals, jnp.float64))[0])
@@ -195,8 +195,8 @@ def test_watermark_admission_pallas_matches_oracle(n, r, seed, drop_p,
     """All three tiers on one instance: the fused Pallas admit kernel must
     agree with the oracle on f32-exact grid instances (duplicate deadlines
     tie-break through the same integer aux key as the float64 paths)."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.vectorized import dom_release_schedule
     from repro.kernels.ops import dom_admit
@@ -204,7 +204,7 @@ def test_watermark_admission_pallas_matches_oracle(n, r, seed, drop_p,
     deadlines, arrivals = _adversarial_dom_instance(
         n, r, seed, grid=True, drop_p=drop_p, late_scale=0.0,
         inf_deadline_p=0.1, kill_receiver=kill_receiver)
-    with enable_x64():
+    with jax.enable_x64(True):
         want = np.asarray(dom_release_schedule(
             jnp.asarray(deadlines, jnp.float64),
             jnp.asarray(arrivals, jnp.float64))[0])
