@@ -21,7 +21,9 @@ Phases:
       fault-operand step program.
 
 Each phase prints one JSON line: committed count, wall and compile
-seconds, the programs compiled with their pow2 batch buckets, and whether
+seconds, the epoch programs traced (`repro.obs`'s ``programs_traced``
+events) with their pow2 batch buckets and the trace, lower and
+compile-or-load seconds of the dispatch that traced them, and whether
 the float64 outputs (log deadlines, client commit times) matched numpy bit
 for bit, with their largest difference in seconds (on the TPU float64 is a
 pair of float32 words, so they need not; the committed log must).  The
@@ -31,7 +33,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import logging
 import sys
 import time
 from pathlib import Path
@@ -51,56 +52,59 @@ def device_check() -> dict:
     return info
 
 
-class CompileLog(logging.Handler):
-    """Backend compiles in this process: program name, shapes, seconds.
+class CompileSpans:
+    """JAX's own compile spans in this process (jax.monitoring): tracing,
+    lowering, and backend compile or persistent-cache load."""
 
-    JAX logs each lowering's argument shapes (at DEBUG) just before the
-    backend compile, whose duration it reports to monitoring listeners.
-    """
+    KINDS = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+             "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+             "/jax/core/compile/backend_compile_duration": "compile_s"}
 
     def __init__(self):
-        super().__init__(logging.DEBUG)
         import jax
-        from jax._src.dispatch import BACKEND_COMPILE_EVENT
 
-        self._event = BACKEND_COMPILE_EVENT
-        self._avals: dict = {}
-        self.rows: list = []
-        log = logging.getLogger("jax._src.interpreters.pxla")
-        log.setLevel(logging.DEBUG)
-        log.propagate = False
-        log.addHandler(self)
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+        self.spans: list = []
+        jax.monitoring.register_event_time_span_listener(self._on_span)
 
-    def emit(self, record):
-        if str(record.msg).startswith("Compiling %s with global shapes"):
-            self._avals[record.args[0]] = record.args[1]
-
-    def _on_event(self, event, secs, **kw):
-        if event == self._event:
-            name = kw.get("fun_name", "?")
-            self.rows.append((name, self._avals.pop(name, None), secs))
+    def _on_span(self, event, start, end, **kw):
+        if event in self.KINDS:
+            self.spans.append((self.KINDS[event], start, end))
 
     def take(self) -> list:
-        rows, self.rows = self.rows, []
-        return rows
+        spans, self.spans = self.spans, []
+        return spans
 
 
-def program_rows(rows) -> list:
-    """The engine's epoch programs among `rows`, with their buckets."""
+def program_rows(rec: dict, spans: list) -> list:
+    """The engine's epoch programs traced in a recording: one row per
+    `programs_traced.*` event, with the compile seconds inside the
+    `fused.dispatch` span that holds it."""
+    dispatch = rec["spans"].get("fused.dispatch", [])
     out = []
-    for name, avals, secs in rows:
-        if name not in ("jit(step)", "jit(scan_fn)") or avals is None:
+    for name, t, shape in rec["events"]:
+        if not name.startswith("programs_traced."):
             continue
-        # step: first 2-D operand is owd_pr [n_pad, R];
-        # scan_fn: first 2-D operand is t [K, n_pad]
-        shape = next(a.shape for a in avals if len(a.shape) == 2)
-        row = {"program": name, "seconds": secs,
-               "bucket": shape[0] if name == "jit(step)" else shape[1]}
-        if name == "jit(scan_fn)":
-            row["K"] = shape[0]
+        a, b = next(((a, b) for a, b in dispatch if a <= t <= b), (t, t))
+        row = {"program": name.split(".", 1)[1], "bucket": shape["n_pad"]}
+        if "K" in shape:
+            row["K"] = shape["K"]
+        for kind in CompileSpans.KINDS.values():
+            row[kind] = union_s([(s, e) for k, s, e in spans
+                                 if k == kind and a <= s and e <= b])
         out.append(row)
     return out
+
+
+def union_s(intervals) -> float:
+    """Length of the union of ``intervals``: JAX traces the jitted helpers
+    a program calls inside the program's own trace span."""
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        a = max(a, end)
+        if b > a:
+            total += b - a
+            end = b
+    return total
 
 
 def counts(result) -> dict:
@@ -118,9 +122,10 @@ def max_abs_diff(a, b) -> float:
     return float(np.max(np.abs(a[fin] - b[fin]), initial=0.0))
 
 
-def run_phase(label: str, tier: str, scenario, compiles: CompileLog):
+def run_phase(label: str, tier: str, scenario, compiles: CompileSpans):
     import numpy as np
 
+    from repro import obs
     from repro.sim.scenario import run_scenario_on_cluster
     from repro.sim.trace import (CommitTrace, assert_trace_ok,
                                  check_adversarial)
@@ -134,10 +139,11 @@ def run_phase(label: str, tier: str, scenario, compiles: CompileLog):
         return result, trace, cluster
 
     compiles.take()
-    t0 = time.perf_counter()
-    res, trace, cluster = run(tier)
-    wall = time.perf_counter() - t0
-    rows = compiles.take()
+    with obs.recording() as rec:
+        t0 = time.perf_counter()
+        res, trace, cluster = run(tier)
+        wall = time.perf_counter() - t0
+    spans = compiles.take()
     ref, ref_trace, _ = run("numpy")
     if not np.array_equal(trace.log_uids, ref_trace.log_uids):
         raise RuntimeError(f"{label}: committed log differs from numpy")
@@ -152,7 +158,7 @@ def run_phase(label: str, tier: str, scenario, compiles: CompileLog):
     # the logs are equal in order, so the deadlines pair up; the client
     # deliveries pair up where they come in the same order
     deliveries_equal = np.array_equal(trace.commit_uids, ref_trace.commit_uids)
-    programs = program_rows(rows)
+    programs = program_rows(rec, spans)
     report = {
         "phase": label, "tier": tier, "scenario": scenario.name,
         "n_requests": res.n_requests, "committed": res.committed,
@@ -164,7 +170,9 @@ def run_phase(label: str, tier: str, scenario, compiles: CompileLog):
         "commit_t_max_abs_diff_s": (
             max_abs_diff(trace.commits["t"], ref_trace.commits["t"])
             if deliveries_equal else None),
-        "wall_s": wall, "compile_s": sum(s for _, _, s in rows),
+        "wall_s": wall,
+        "compile_s": union_s([(s, e) for k, s, e in spans
+                              if k == "compile_s"]),
         "programs": programs,
         "buckets": sorted({p["bucket"] for p in programs}),
     }
@@ -182,7 +190,7 @@ def pallas_custom_call(cluster, programs) -> bool:
     scan = cluster.engine.tier.epoch_scan(
         cfg.f, use_kcls=bool(getattr(cfg, "commutative", False)),
         use_cap=float(getattr(cfg, "deadline_cap", 0.0) or 0.0) > 0.0)
-    used = next(p for p in programs if p["program"] == "jit(scan_fn)")
+    used = next(p for p in programs if p["program"] == "scan")
     args = _fused_scan_args(used["K"], used["bucket"], cluster.engine.n,
                             window=cfg.dom.window)
     with jax.enable_x64(True):
@@ -197,7 +205,7 @@ def main() -> None:
 
     info = device_check()
     print("compile_cache", enable_compile_cache(), flush=True)
-    compiles = CompileLog()
+    compiles = CompileSpans()
     base = get_scenario("intra-zone")
     at_scale = dataclasses.replace(
         base, name="intra-zone-200k",

@@ -84,6 +84,7 @@ from typing import Optional, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core.clocksync import estimate_offsets
 from repro.core.quorum import fast_quorum_size, slow_quorum_size
 from repro.core.recovery import (
@@ -559,7 +560,8 @@ def _build_epoch_body(tier: ComputeTier, f: int, use_kcls: bool,
     fq = fast_quorum_size(f)
     sq = slow_quorum_size(f)
 
-    def pool_fold(pool, ptr, cnt, obs, n_valid):
+    @jax.named_scope("pool_fold")
+    def pool_fold(pool, ptr, cnt, samples, n_valid):
         # Ring-buffer fold of this epoch's observed OWD samples, row-major
         # over the valid rows -- the device twin of update_bound's
         # `concat(pool, obs)[-W:]`: when more than W samples would land,
@@ -567,16 +569,17 @@ def _build_epoch_body(tier: ComputeTier, f: int, use_kcls: bool,
         # distinct (mode="drop" discards masked lanes at index W), so the
         # scatter is deterministic.
         W = pool.shape[0]
-        n_pad, R = obs.shape
+        n_pad, R = samples.shape
         m = n_valid * R
         m_kept = jnp.minimum(m, W)
         skip = m - m_kept
         k = jnp.arange(n_pad * R)
         write = (k >= skip) & (k < m)
         tgt = jnp.where(write, (ptr + k - skip) % W, W)
-        pool = pool.at[tgt].set(obs.ravel(), mode="drop")
+        pool = pool.at[tgt].set(samples.ravel(), mode="drop")
         return pool, (ptr + m_kept) % W, jnp.minimum(cnt + m, W)
 
+    @jax.named_scope("bound")
     def pool_percentile(pool, cnt, pq01, margin, clamp_d):
         # Device mirror of update_bound: sort-select the two order
         # statistics (+inf fills the unfilled tail) and interpolate exactly
@@ -600,6 +603,7 @@ def _build_epoch_body(tier: ComputeTier, f: int, use_kcls: bool,
         bound = jnp.where((bound > 0.0) & (bound < clamp_d), bound, clamp_d)
         return jnp.where(cnt > 0, bound, clamp_d)
 
+    @jax.named_scope("fetch")
     def tree_mean_fetch(reply):
         # `_fetch_estimate` on device: the fold-halves tree sum is
         # pow2-padding-invariant, so the padded batch reduces to the exact
@@ -635,107 +639,116 @@ def _build_epoch_body(tier: ComputeTier, f: int, use_kcls: bool,
         # --- bound: device-resident sliding-percentile deadline bound ------
         # Fold BEFORE selecting, mirroring StampStage's update_bound call
         # (this epoch's samples are part of its own bound).
-        obs = owd_eff
+        samples = owd_eff
         if stamp_off is not None:
-            obs = owd_eff + arr_off - stamp_off[:, None]
-        pool, ptr, cnt = pool_fold(pool, ptr, cnt, obs, n_valid)
+            samples = owd_eff + arr_off - stamp_off[:, None]
+        pool, ptr, cnt = pool_fold(pool, ptr, cnt, samples, n_valid)
         bound = pool_percentile(pool, cnt, pq01, margin, clamp_d)
         # --- fetch: device-resident mean-reply estimate --------------------
         reply = jnp.where(alive[None, :], reply_owd, jnp.inf)
         fetch = tree_mean_fetch(reply)
-        # --- stamp: proxy stamping + deadline bounding ---------------------
-        # stamp_off: proxy clock-read error folded into the deadline value;
-        # arr_off: replica clock-read error shifting each receiver's local
-        # frame (admission compares + release instants). Clock-fault-free
-        # epochs omit both (None): the synced-clock program carries no
-        # offset operands at all, keeping the PR-3 hot path untaxed.
-        stamp = t + c2p
-        deadlines = stamp + bound
-        if stamp_off is not None:
-            deadlines = deadlines + stamp_off
-        if pre_dl is not None:
-            # Sharded multi-op entries carry a pre-stamped global deadline
-            # (dl > 0): the proxy forwards it untouched so every involved
-            # group orders the op at the identical synchronized-time slot.
-            # The override is LAST -- the deadline was fixed client-side, so
-            # proxy-clock error does not re-bias it. 0.0 = stamp normally.
-            deadlines = jnp.where(pre_dl > 0, pre_dl, deadlines)
-        arrivals = jnp.where(drop_eff | ~alive[None, :], jnp.inf,
-                             stamp[:, None] + owd_eff)
-        # recovery stall: nothing releases before `floor` (StartView); a zero
-        # floor is the identity, mirroring StampStage's op order exactly
-        arrivals = jnp.maximum(arrivals, floor)
-        if dies_at is not None:
-            # crash-epoch fidelity: in-flight messages to a replica dying at
-            # the epoch's end are never received (optional operand, like the
-            # clock offsets -- crash-free epochs carry none of this)
-            arrivals = jnp.where(arrivals > dies_at[None, :], jnp.inf,
-                                 arrivals)
-        # --- dom: watermark admission + release (receiver-local frames) ----
-        a_loc = arrivals if arr_off is None else arrivals + arr_off
-        admitted = tier.admit_traced(deadlines, a_loc)
-        release = jnp.where(admitted,
-                            jnp.maximum(deadlines[:, None], a_loc),
-                            jnp.inf)
-        if arr_off is not None:
-            release = release - arr_off
-        # --- deadline cap (SD.2.4): leader releases far-future deadlines
-        # at arrival; those requests are barred from the fast path. The
-        # program is specialized on use_cap (like use_kcls), so cap-free
-        # runs carry none of this masking work.
-        lead_col = jnp.arange(R)[None, :] == leader
-        if use_cap:
-            capped = jnp.isfinite(a_loc[:, leader]) \
-                & (deadlines > a_loc[:, leader] + cap)
-            admitted = admitted | (lead_col & capped[:, None])
-            release = jnp.where(lead_col & capped[:, None], arrivals, release)
+        with jax.named_scope("stamp"):
+            # --- stamp: proxy stamping + deadline bounding -------------------
+            # stamp_off: proxy clock-read error folded into the deadline
+            # value; arr_off: replica clock-read error shifting each
+            # receiver's local frame (admission compares + release
+            # instants). Clock-fault-free epochs omit both (None): the
+            # synced-clock program carries no offset operands at all,
+            # keeping the PR-3 hot path untaxed.
+            stamp = t + c2p
+            deadlines = stamp + bound
+            if stamp_off is not None:
+                deadlines = deadlines + stamp_off
+            if pre_dl is not None:
+                # Sharded multi-op entries carry a pre-stamped global
+                # deadline (dl > 0): the proxy forwards it untouched so
+                # every involved group orders the op at the identical
+                # synchronized-time slot. The override is LAST -- the
+                # deadline was fixed client-side, so proxy-clock error does
+                # not re-bias it. 0.0 = stamp normally.
+                deadlines = jnp.where(pre_dl > 0, pre_dl, deadlines)
+            arrivals = jnp.where(drop_eff | ~alive[None, :], jnp.inf,
+                                 stamp[:, None] + owd_eff)
+            # recovery stall: nothing releases before `floor` (StartView);
+            # a zero floor is the identity, mirroring StampStage's op order
+            # exactly
+            arrivals = jnp.maximum(arrivals, floor)
+            if dies_at is not None:
+                # crash-epoch fidelity: in-flight messages to a replica
+                # dying at the epoch's end are never received (optional
+                # operand, like the clock offsets -- crash-free epochs carry
+                # none of this)
+                arrivals = jnp.where(arrivals > dies_at[None, :], jnp.inf,
+                                     arrivals)
+        with jax.named_scope("admit"):
+            # --- dom: watermark admission + release (receiver-local frames)
+            a_loc = arrivals if arr_off is None else arrivals + arr_off
+            admitted = tier.admit_traced(deadlines, a_loc)
+            release = jnp.where(admitted,
+                                jnp.maximum(deadlines[:, None], a_loc),
+                                jnp.inf)
+            if arr_off is not None:
+                release = release - arr_off
+            # --- deadline cap (SD.2.4): leader releases far-future deadlines
+            # at arrival; those requests are barred from the fast path. The
+            # program is specialized on use_cap (like use_kcls), so cap-free
+            # runs carry none of this masking work.
+            lead_col = jnp.arange(R)[None, :] == leader
+            if use_cap:
+                capped = jnp.isfinite(a_loc[:, leader]) \
+                    & (deadlines > a_loc[:, leader] + cap)
+                admitted = admitted | (lead_col & capped[:, None])
+                release = jnp.where(lead_col & capped[:, None], arrivals,
+                                    release)
         # --- commit: prefix hash-consistency vs the leader ------------------
-        order = tier.order_traced(deadlines)
-        if use_kcls:
-            order = order[jnp.argsort(kcls[order], stable=True)]
-        adm_sorted = admitted[order]
-        lead_adm_sorted = adm_sorted[:, leader]
-        disagree = adm_sorted != lead_adm_sorted[:, None]
-        cum_disagree = jnp.cumsum(disagree, axis=0) - disagree
-        if use_kcls:
-            ks = kcls[order]
-            is_start = jnp.concatenate(
-                [jnp.ones((1,), bool), ks[1:] != ks[:-1]])
-            start_pos = jax.lax.cummax(
-                jnp.where(is_start, jnp.arange(N), 0))
-            cum_disagree = cum_disagree - cum_disagree[start_pos]
-        prefix_match = cum_disagree == 0
-        inv = jnp.zeros((N,), order.dtype).at[order].set(
-            jnp.arange(N, dtype=order.dtype))
-        prefix_match = prefix_match[inv]
-        # --- fast quorum ----------------------------------------------------
-        lead_admitted = admitted[:, leader]
-        fast_reply_t = jnp.where(admitted, release + reply, jnp.inf)
-        fast_hash_ok = admitted & prefix_match & lead_admitted[:, None]
-        ok_t = jnp.where(fast_hash_ok, fast_reply_t, jnp.inf)
-        ok_lead = ok_t[:, leader]
-        ok_kth = (jnp.sort(ok_t, axis=1)[:, fq - 1] if fq - 1 < R
-                  else jnp.full((N,), jnp.inf))
-        fast_commit_t = jnp.where(jnp.isfinite(ok_lead), ok_kth, jnp.inf)
-        fast_commit_t = jnp.maximum(fast_commit_t, ok_lead)
-        if use_cap:
-            fast_commit_t = jnp.where(capped, jnp.inf, fast_commit_t)
-        # --- slow path ------------------------------------------------------
-        arr_lead = arrivals[:, leader]
-        leader_t = jnp.where(lead_admitted, release[:, leader], arr_lead)
-        leader_t = jnp.where(jnp.isfinite(arr_lead), leader_t, jnp.inf)
-        sync_t = leader_t[:, None] + batch_delay + reply
-        have_t = jnp.where(jnp.isfinite(arrivals), arrivals,
-                           leader_t[:, None] + fetch)
-        slow_reply_t = jnp.maximum(sync_t, have_t) + reply
-        slow_reply_t = jnp.where(lead_col, leader_t[:, None] + reply,
-                                 slow_reply_t)
-        slow_kth = jnp.sort(slow_reply_t, axis=1)[:, sq - 1]
-        slow_commit_t = jnp.maximum(slow_kth, leader_t + reply[:, leader])
-        # --- verdicts -------------------------------------------------------
-        commit_t = jnp.minimum(fast_commit_t, slow_commit_t)
-        fast = fast_commit_t <= slow_commit_t
-        committed = jnp.isfinite(commit_t)
+        with jax.named_scope("order"):
+            order = tier.order_traced(deadlines)
+            if use_kcls:
+                order = order[jnp.argsort(kcls[order], stable=True)]
+        with jax.named_scope("commit"):
+            adm_sorted = admitted[order]
+            lead_adm_sorted = adm_sorted[:, leader]
+            disagree = adm_sorted != lead_adm_sorted[:, None]
+            cum_disagree = jnp.cumsum(disagree, axis=0) - disagree
+            if use_kcls:
+                ks = kcls[order]
+                is_start = jnp.concatenate(
+                    [jnp.ones((1,), bool), ks[1:] != ks[:-1]])
+                start_pos = jax.lax.cummax(
+                    jnp.where(is_start, jnp.arange(N), 0))
+                cum_disagree = cum_disagree - cum_disagree[start_pos]
+            prefix_match = cum_disagree == 0
+            inv = jnp.zeros((N,), order.dtype).at[order].set(
+                jnp.arange(N, dtype=order.dtype))
+            prefix_match = prefix_match[inv]
+            # --- fast quorum -------------------------------------------------
+            lead_admitted = admitted[:, leader]
+            fast_reply_t = jnp.where(admitted, release + reply, jnp.inf)
+            fast_hash_ok = admitted & prefix_match & lead_admitted[:, None]
+            ok_t = jnp.where(fast_hash_ok, fast_reply_t, jnp.inf)
+            ok_lead = ok_t[:, leader]
+            ok_kth = (jnp.sort(ok_t, axis=1)[:, fq - 1] if fq - 1 < R
+                      else jnp.full((N,), jnp.inf))
+            fast_commit_t = jnp.where(jnp.isfinite(ok_lead), ok_kth, jnp.inf)
+            fast_commit_t = jnp.maximum(fast_commit_t, ok_lead)
+            if use_cap:
+                fast_commit_t = jnp.where(capped, jnp.inf, fast_commit_t)
+            # --- slow path ---------------------------------------------------
+            arr_lead = arrivals[:, leader]
+            leader_t = jnp.where(lead_admitted, release[:, leader], arr_lead)
+            leader_t = jnp.where(jnp.isfinite(arr_lead), leader_t, jnp.inf)
+            sync_t = leader_t[:, None] + batch_delay + reply
+            have_t = jnp.where(jnp.isfinite(arrivals), arrivals,
+                               leader_t[:, None] + fetch)
+            slow_reply_t = jnp.maximum(sync_t, have_t) + reply
+            slow_reply_t = jnp.where(lead_col, leader_t[:, None] + reply,
+                                     slow_reply_t)
+            slow_kth = jnp.sort(slow_reply_t, axis=1)[:, sq - 1]
+            slow_commit_t = jnp.maximum(slow_kth, leader_t + reply[:, leader])
+            # --- verdicts ----------------------------------------------------
+            commit_t = jnp.minimum(fast_commit_t, slow_commit_t)
+            fast = fast_commit_t <= slow_commit_t
+            committed = jnp.isfinite(commit_t)
         outs = (stamp, deadlines, arrivals, admitted, release,
                 commit_t, fast & committed, committed, bound)
         if sync_theta is not None:
@@ -745,12 +758,20 @@ def _build_epoch_body(tier: ComputeTier, f: int, use_kcls: bool,
             # operands -- round-free epochs carry none of this), emitting
             # the per-node offset estimates and honest error bounds the
             # daemon folds into corrections at the epoch boundary.
-            sync_est, sync_sigma = estimate_offsets(
-                sync_theta, sync_rtt, jnp, sync_safety, sync_floor)
+            with jax.named_scope("sync_estimate"):
+                sync_est, sync_sigma = estimate_offsets(
+                    sync_theta, sync_rtt, jnp, sync_safety, sync_floor)
             outs = outs + (sync_est, sync_sigma)
         return ((pool, ptr, cnt), outs)
 
     return body
+
+
+def _count_trace(program: str, **shape) -> None:
+    """Called from an epoch program's Python body, which runs only while
+    JAX traces it: once per (program object, operand signature)."""
+    obs.count("programs_traced." + program)
+    obs.event("programs_traced." + program, **shape)
 
 
 def _build_fused_step(tier: ComputeTier, f: int, use_kcls: bool,
@@ -774,6 +795,7 @@ def _build_fused_step(tier: ComputeTier, f: int, use_kcls: bool,
              pair_drop=None, pair_delay=None, pre_dl=None,
              sync_theta=None, sync_rtt=None, sync_safety=None,
              sync_floor=None):
+        _count_trace("step", n_pad=t.shape[0])
         carry, outs = body(pool, ptr, cnt, t, c2p, owd_pr, drop_pr,
                            reply_owd, alive, kcls, leader, n_valid, pq01,
                            margin, clamp_d, batch_delay, cap, floor,
@@ -807,6 +829,8 @@ def _build_fused_scan(tier: ComputeTier, f: int, use_kcls: bool,
     def scan_fn(pool, ptr, cnt, t, c2p, owd_pr, drop_pr, reply_owd, kcls,
                 n_valid, alive, leader, pq01, margin, clamp_d, batch_delay,
                 cap, floor):
+        _count_trace("scan", K=t.shape[0], n_pad=t.shape[1])
+
         def one_epoch(carry, xs):
             pool, ptr, cnt = carry
             tk, c2pk, owdk, dropk, replyk, kclsk, nvk = xs
@@ -1129,6 +1153,33 @@ class FusedEpochStage(Stage):
     def run(self, s, eng):
         import jax
 
+        with obs.span("fused.pack"):
+            cap = float(getattr(eng.cfg, "deadline_cap", 0.0) or 0.0)
+            step = eng.tier.epoch_step(eng.cfg.f, use_kcls=s.kcls is not None,
+                                       use_cap=cap > 0.0)
+            args, kw = self._pack(s, eng, cap)
+        with jax.enable_x64(True):
+            with obs.span("fused.dispatch"):
+                out = step(*args, **kw)
+            with obs.span("fused.pull"):
+                pulled = (out[:8] if s.sync_theta is None
+                          else out[:8] + out[9:11])
+                # lint: allow[HS003] THE one epoch-end device->host pull of the fused program's outputs
+                pulled = [np.asarray(o) for o in pulled]
+        with obs.span("fused.unpack"):
+            N = s.t.size
+            (s.stamp, s.deadlines, s.arrivals, s.admitted, s.release,
+             s.commit_time, s.fast, s.committed) = [o[:N] for o in pulled[:8]]
+            if s.sync_theta is not None:
+                # the round's estimator outputs land at the epoch boundary:
+                # corrections/bounds fold exactly where the staged tier's
+                # numpy twin folds them (run_epoch's epilogue)
+                s.sync_est, s.sync_sigma = pulled[8], pulled[9]
+                eng.clocksync.consume_round(s.sync_est, s.sync_sigma)
+
+    @staticmethod
+    def _pack(s, eng, cap: float) -> tuple:
+        """The epoch program's padded operands: (args, kwargs)."""
         cfg = eng.cfg
         N = s.t.size
         R = eng.n
@@ -1192,29 +1243,13 @@ class FusedEpochStage(Stage):
             fault_kw["sync_rtt"] = s.sync_rtt
             fault_kw["sync_safety"] = np.float64(cfg.clock.sigma_safety)
             fault_kw["sync_floor"] = np.float64(cfg.clock.sigma_floor)
-        cap = float(getattr(cfg, "deadline_cap", 0.0) or 0.0)
-        step = eng.tier.epoch_step(cfg.f, use_kcls=s.kcls is not None,
-                                   use_cap=cap > 0.0)
-        with jax.enable_x64(True):
-            out = step(pool, ptr, cnt, t, c2p, owd, drop, reply,
-                       np.asarray(s.alive, bool), kcls, s.leader, N,
-                       float(cfg.dom.percentile) / 100.0, eng.bound_margin(),
-                       float(cfg.dom.clamp_d),
-                       float(cfg.leader_batch_delay),
-                       cap, float(s.release_floor), **fault_kw)
-            pulled = (out[:8] if s.sync_theta is None
-                      else out[:8] + out[9:11])
-            # lint: allow[HS003] THE one epoch-end device->host pull of the fused program's outputs
-            pulled = [np.asarray(o) for o in pulled]
-        (s.stamp, s.deadlines, s.arrivals, s.admitted, s.release,
-         s.commit_time, s.fast, s.committed) = [o[:N] for o in pulled[:8]]
-        if s.sync_theta is not None:
-            # the round's estimator outputs land at the epoch boundary:
-            # corrections/bounds fold exactly where the staged tier's
-            # numpy twin folds them (run_epoch's epilogue)
-            s.sync_est, s.sync_sigma = pulled[8], pulled[9]
-            eng.clocksync.consume_round(s.sync_est, s.sync_sigma)
+        args = (pool, ptr, cnt, t, c2p, owd, drop, reply,
+                np.asarray(s.alive, bool), kcls, s.leader, N,
+                float(cfg.dom.percentile) / 100.0, eng.bound_margin(),
+                float(cfg.dom.clamp_d), float(cfg.leader_batch_delay),
+                cap, float(s.release_floor))
         s.reply_owd = rep
+        return args, fault_kw
 
 
 class CommitStage(Stage):
@@ -1703,7 +1738,8 @@ class DomEngine:
         and queue any due probe round; no-op without a daemon. The cluster
         calls this once per epoch BEFORE running it."""
         if self.clocksync is not None:
-            self.clocksync.advance(float(t_end))
+            with obs.span("sync"):
+                self.clocksync.advance(float(t_end))
 
     def _ensure_pair_state(self) -> None:
         if self._pair_block is None:
@@ -1870,7 +1906,8 @@ class DomEngine:
             # others keep the unmodified (scan-eligible) program shape
             s.pre_deadline = dl
         for stage in self.stages:
-            stage.run(s, self)
+            with obs.span(stage.name):
+                stage.run(s, self)
         if self.clocksync is not None and self.clocksync.pending is not None:
             # staged tier: the due probe round lands via the numpy twin of
             # the in-program estimator at the SAME epoch slot the fused
@@ -1938,10 +1975,55 @@ class DomEngine:
                 leader=int(leader),
                 release_floor=float(release_floor),
             )
-            sample.run(s, self)
+            with obs.span(sample.name):
+                sample.run(s, self)
             states[i] = s
         if all(s is None for s in states):
             return states
+        with obs.span("fused.pack"):
+            cap = float(getattr(cfg, "deadline_cap", 0.0) or 0.0)
+            scan = self.tier.epoch_scan(cfg.f, use_kcls=commutative,
+                                        use_cap=cap > 0.0)
+            args = self._stack_window(states, alive, leader, release_floor,
+                                      cap)
+        with jax.enable_x64(True):
+            with obs.span("fused.dispatch"):
+                out = scan(*args)
+            with obs.span("fused.pull"):
+                # lint: allow[HS003] the ONE per-window pull: K scanned epochs of fused outputs in a single transfer
+                ys = [np.asarray(o) for o in out[:8]]
+        check = getattr(self.tier, "check_epoch", None)
+        for i, s in enumerate(states):
+            if s is None:
+                continue
+            with obs.span("fused.unpack"):
+                N = s.t.size
+                (s.stamp, s.deadlines, s.arrivals, s.admitted, s.release,
+                 s.commit_time, s.fast, s.committed) = \
+                    [y[i][:N] for y in ys]
+                # advance the host pool mirror in epoch order;
+                # bit-identical to the scanned device fold by construction
+                s.bound = self.update_bound(self.observed_owd_samples(s))
+                rep = s.reply_owd.copy()
+                rep[:, ~alive] = np.inf
+                s.reply_owd = rep
+                # every tier's device order now equals the stable argsort
+                # exactly (int-key kernels break ties by message id), so
+                # the log's execution order needs no extra device round trip
+                s.exec_order = np.argsort(s.deadlines, kind="stable")
+            with obs.span(deliver.name):
+                deliver.run(s, self)
+            with obs.span(log.name):
+                log.run(s, self)
+            if check is not None:   # SanitizerTier (repro.core.sanitizer)
+                check(s, self)
+        return states
+
+    def _stack_window(self, states: list, alive: np.ndarray, leader: int,
+                      release_floor: float, cap: float) -> tuple:
+        """The scan program's operands, stacked [K, n_pad(, R)]."""
+        cfg = self.cfg
+        K = len(states)
         R = self.n
         n_pad = max(_pow2_bucket(s.t.size) if self.tier.pad_batches
                     else s.t.size for s in states if s is not None)
@@ -1967,42 +2049,11 @@ class DomEngine:
             if s.kcls is not None:
                 kcls[i, :N] = s.kcls
             n_valid[i] = N
-        cap = float(getattr(cfg, "deadline_cap", 0.0) or 0.0)
-        scan = self.tier.epoch_scan(cfg.f, use_kcls=commutative,
-                                    use_cap=cap > 0.0)
         pool, ptr, cnt = self.device_pool_state()
-        with jax.enable_x64(True):
-            out = scan(pool, ptr, cnt, t, c2p, owd, drop, reply, kcls,
-                       n_valid, alive, int(leader),
-                       float(cfg.dom.percentile) / 100.0, self.bound_margin(),
-                       float(cfg.dom.clamp_d),
-                       float(cfg.leader_batch_delay), cap,
-                       float(release_floor))
-            # lint: allow[HS003] the ONE per-window pull: K scanned epochs of fused outputs in a single transfer
-            ys = [np.asarray(o) for o in out[:8]]
-        check = getattr(self.tier, "check_epoch", None)
-        for i, s in enumerate(states):
-            if s is None:
-                continue
-            N = s.t.size
-            (s.stamp, s.deadlines, s.arrivals, s.admitted, s.release,
-             s.commit_time, s.fast, s.committed) = \
-                [y[i][:N] for y in ys]
-            # advance the host pool mirror in epoch order; bit-identical to
-            # the scanned device fold by construction
-            s.bound = self.update_bound(self.observed_owd_samples(s))
-            rep = s.reply_owd.copy()
-            rep[:, ~alive] = np.inf
-            s.reply_owd = rep
-            # every tier's device order now equals the stable argsort
-            # exactly (int-key kernels break ties by message id), so the
-            # log's execution order needs no extra device round trip
-            s.exec_order = np.argsort(s.deadlines, kind="stable")
-            deliver.run(s, self)
-            log.run(s, self)
-            if check is not None:   # SanitizerTier (repro.core.sanitizer)
-                check(s, self)
-        return states
+        return (pool, ptr, cnt, t, c2p, owd, drop, reply, kcls, n_valid,
+                alive, int(leader), float(cfg.dom.percentile) / 100.0,
+                self.bound_margin(), float(cfg.dom.clamp_d),
+                float(cfg.leader_batch_delay), cap, float(release_floor))
 
 
 __all__ = [

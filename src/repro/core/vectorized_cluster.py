@@ -72,6 +72,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.cluster import CommonConfig, Cluster, summarize_commits
 from repro.core.dom import DomParams
 from repro.core.engine import SCAN_K_BUCKETS, DomEngine, PendingBuffer
@@ -543,30 +544,33 @@ class VectorizedNezhaCluster(Cluster):
         and re-admitted into the next epoch's DOM stage (proxy retransmit
         at StartView).
         """
-        vc = self._vc
-        t_rec = vc.t_done
-        # Only reachable survivors take part in MERGE-LOG and install the
-        # merged log at StartView; a partitioned-away replica stays on its
-        # frozen state until the heal lets it catch up.
-        res = self.engine.logs.view_change(vc.view, self._reachable)
-        rec, dropped = res["recovered"], res["dropped"]
-        self._view = vc.view
-        self._last_leader = vc.leader
-        self._release_floor = max(self._release_floor, t_rec)
-        self._vc = None
-        self._recovered_entries += int(rec["cid"].size)
-        self._dropped_speculative += int(dropped["cid"].size)
-        self.view_change_events.append({
-            "view": vc.view, "leader": vc.leader, "t_start": vc.t_start,
-            "t_done": t_rec, "recovered": int(rec["cid"].size),
-            "dropped": int(dropped["cid"].size),
-        })
-        if dropped["cid"].size:
-            # proxies retransmit un-merged entries at StartView: their
-            # pending retry is pulled up to the recovery-completion instant
-            self._pending.reschedule_uids(dropped["cid"], dropped["rid"], t_rec)
-        if rec["cid"].size:
-            self._deliver_recovered(rec, vc.leader, t_rec)
+        with obs.span("recovery"):
+            vc = self._vc
+            t_rec = vc.t_done
+            # Only reachable survivors take part in MERGE-LOG and install
+            # the merged log at StartView; a partitioned-away replica stays
+            # on its frozen state until the heal lets it catch up.
+            res = self.engine.logs.view_change(vc.view, self._reachable)
+            rec, dropped = res["recovered"], res["dropped"]
+            self._view = vc.view
+            self._last_leader = vc.leader
+            self._release_floor = max(self._release_floor, t_rec)
+            self._vc = None
+            self._recovered_entries += int(rec["cid"].size)
+            self._dropped_speculative += int(dropped["cid"].size)
+            self.view_change_events.append({
+                "view": vc.view, "leader": vc.leader, "t_start": vc.t_start,
+                "t_done": t_rec, "recovered": int(rec["cid"].size),
+                "dropped": int(dropped["cid"].size),
+            })
+            if dropped["cid"].size:
+                # proxies retransmit un-merged entries at StartView: their
+                # pending retry is pulled up to the recovery-completion
+                # instant
+                self._pending.reschedule_uids(dropped["cid"], dropped["rid"],
+                                              t_rec)
+            if rec["cid"].size:
+                self._deliver_recovered(rec, vc.leader, t_rec)
 
     def _deliver_recovered(self, rec: dict, leader: int, t_rec: float) -> None:
         cfg = self.cfg
@@ -612,10 +616,12 @@ class VectorizedNezhaCluster(Cluster):
         horizon = self._now + duration
         ep = self.cfg.epoch_duration
         while self._now < horizon:
-            self._apply_faults(self._now)
-            # _apply_faults consumed every event at or before now, so both
-            # candidates are strictly ahead and the loop always advances.
-            self._update_view(self._now)
+            with obs.span("loop.faults"):
+                self._apply_faults(self._now)
+                # _apply_faults consumed every event at or before now, so
+                # both candidates are strictly ahead and the loop always
+                # advances.
+                self._update_view(self._now)
             candidates = [horizon, self._now + ep, self._next_fault_time()]
             if self._vc is not None and np.isfinite(self._vc.t_done):
                 candidates.append(self._vc.t_done)
@@ -628,24 +634,30 @@ class VectorizedNezhaCluster(Cluster):
                 # recovery stall: replicas are in VIEWCHANGE status; pending
                 # requests wait in the proxies/early buffers until StartView
                 self.epoch_leaders.append(self._vc.leader)
+                obs.count("epochs.stalled")
             elif self._vc is not None or not self._alive.any():
                 # total outage, or a view change that CANNOT complete (below
                 # the f+1 quorum): the cluster is unresponsive indefinitely,
                 # so clients time out and retry until abandonment -- same
                 # accounting as the event backend, no silently-held requests
                 while True:
-                    due = self._pending.pop_due(epoch_end)
+                    with obs.span("loop.pending"):
+                        due = self._pending.pop_due(epoch_end)
                     if due.size == 0:
                         break
                     self._batches += 1
                     self._retry(due)
                 self.epoch_leaders.append(
                     self._vc.leader if self._vc is not None else -1)
+                obs.count("epochs.stalled")
             else:
-                k_scan = self._scan_window_len(horizon)
+                k_scan, fence = self._scan_window(horizon)
                 if k_scan:
+                    obs.count("epochs.scan", k_scan)
                     self._run_scan_window(k_scan)
                     continue
+                obs.count("epochs.step")
+                obs.count("scan_fence." + fence)
                 leader = leader_of_view(self._view, self.f)
                 self._run_epoch_batches(epoch_end, leader,
                                         self._deaths_at(epoch_end))
@@ -659,10 +671,13 @@ class VectorizedNezhaCluster(Cluster):
             self._now = epoch_end
 
     # -- the K-epoch scan fast path ----------------------------------------------
-    def _scan_window_len(self, horizon: float) -> int:
-        """Largest SCAN_K_BUCKETS window the fast path may dispatch now.
+    def _scan_window(self, horizon: float) -> tuple[int, Optional[str]]:
+        """Largest SCAN_K_BUCKETS window the fast path may dispatch now,
+        and the fence that refused it: ``(k, None)`` or ``(0, reason)``.
 
-        0 when the scan path is off or ineligible.  A window of K epochs is
+        k is 0 when the scan path is off or ineligible; the reason names
+        the first guard below that refused (for the bucket guards, the
+        one that refused the smallest bucket).  A window of K epochs is
         eligible only when the device program can be segment-free: a fused
         tier, no view change in flight (caller's branch), synced clocks, no
         callbacks (closed-loop resubmission re-times epochs), every epoch a
@@ -677,26 +692,40 @@ class VectorizedNezhaCluster(Cluster):
         timing is bit-identical.
         """
         cfg = self.cfg
+        eng = self.engine
+        if int(getattr(cfg, "epochs_per_dispatch", 1)) < min(SCAN_K_BUCKETS):
+            return 0, "epochs_per_dispatch"
+        for refused, reason in (
+                (not eng.tier.fused, "tier"),
+                (self.on_commit is not None, "callbacks"),
+                (eng.clocks_faulty, "clock_fault"),
+                (eng.pairs_faulty, "pair_fault"),
+                (eng.stampers_biased, "stamp_bias"),
+                (eng.sync_active, "sync")):
+            if refused:
+                return 0, reason
+        if self._pending.has_prestamped():
+            return 0, "prestamped"
         k_max = int(getattr(cfg, "epochs_per_dispatch", 1))
-        if k_max < min(SCAN_K_BUCKETS) or not self.engine.tier.fused \
-                or self.on_commit is not None or self.engine.clocks_faulty \
-                or self.engine.pairs_faulty or self.engine.stampers_biased \
-                or self.engine.sync_active \
-                or self._pending.has_prestamped():
-            return 0
         t_min = self._pending.min_time()
         retry_closed = t_min + cfg.client_timeout
         fault = self._next_fault_time()
+        reason = None
         for k in sorted(SCAN_K_BUCKETS, reverse=True):
             if k > k_max:
                 continue
             end = self._now
             for _ in range(k):
                 end = end + cfg.epoch_duration
-            if end <= horizon and fault > end and end < retry_closed \
-                    and t_min < end:
-                return k
-        return 0
+            for refused, reason in ((end > horizon, "horizon"),
+                                    (fault <= end, "fault_event"),
+                                    (end >= retry_closed, "retry_closure"),
+                                    (t_min >= end, "nothing_pending")):
+                if refused:
+                    break
+            else:
+                return k, None
+        return 0, reason
 
     def _run_scan_window(self, k: int) -> None:
         """Dispatch K consecutive fault-free epochs through the engine's
@@ -711,7 +740,8 @@ class VectorizedNezhaCluster(Cluster):
         for _ in range(k):
             e = e + ep
             ends.append(e)
-        dues = [self._pending.pop_due(t) for t in ends]
+        with obs.span("loop.pending"):
+            dues = [self._pending.pop_due(t) for t in ends]
         states = self.engine.run_epoch_window(dues, self._alive, leader,
                                               self._release_floor)
         for due, s in zip(dues, states):
@@ -760,40 +790,42 @@ class VectorizedNezhaCluster(Cluster):
         fast-path accounting, the commit trace, retries, and closed-loop
         callbacks. Identical order of operations on every path (bit parity).
         """
-        self._batches += 1
-        # stamp audit for check_stamp_bias: per-message (proxy id,
-        # deadline - true stamp instant) = bound (+ bias + clock error);
-        # attempts whose client leg was dropped never got stamped
-        fin = np.isfinite(s.stamp)
-        self._trace_stamps.append(
-            (s.cid[fin] % self.cfg.n_proxies,
-             s.deadlines[fin] - s.stamp[fin]))
-        self._latencies.append(s.latency[s.delivered])
-        self._n_fast += int(np.sum(s.fast & s.delivered))
-        if s.delivered.any():
-            idx = np.flatnonzero(s.delivered)
-            self._trace_commits.append((
-                s.commit_at_client[idx], s.cid[idx], s.rid[idx],
-                (s.fast & s.delivered)[idx], np.zeros(idx.size, bool)))
-        if not s.delivered.all():
-            self._retry(due[~s.delivered])
-        if self.on_commit is not None and s.delivered.any():
-            idx = np.flatnonzero(s.delivered)
-            idx = idx[np.argsort(s.commit_at_client[idx], kind="stable")]
-            t_save = self._now
-            for i in idx:
-                # callbacks observe the commit's client-side time, so a
-                # closed-loop resubmission is stamped when the reply lands
-                self._now = float(s.commit_at_client[i])
-                self.on_commit(int(s.cid[i]), int(s.rid[i]))
-            self._now = t_save
+        with obs.span("loop.absorb"):
+            self._batches += 1
+            # stamp audit for check_stamp_bias: per-message (proxy id,
+            # deadline - true stamp instant) = bound (+ bias + clock error);
+            # attempts whose client leg was dropped never got stamped
+            fin = np.isfinite(s.stamp)
+            self._trace_stamps.append(
+                (s.cid[fin] % self.cfg.n_proxies,
+                 s.deadlines[fin] - s.stamp[fin]))
+            self._latencies.append(s.latency[s.delivered])
+            self._n_fast += int(np.sum(s.fast & s.delivered))
+            if s.delivered.any():
+                idx = np.flatnonzero(s.delivered)
+                self._trace_commits.append((
+                    s.commit_at_client[idx], s.cid[idx], s.rid[idx],
+                    (s.fast & s.delivered)[idx], np.zeros(idx.size, bool)))
+            if not s.delivered.all():
+                self._retry(due[~s.delivered])
+            if self.on_commit is not None and s.delivered.any():
+                idx = np.flatnonzero(s.delivered)
+                idx = idx[np.argsort(s.commit_at_client[idx], kind="stable")]
+                t_save = self._now
+                for i in idx:
+                    # callbacks observe the commit's client-side time, so a
+                    # closed-loop resubmission is stamped when the reply lands
+                    self._now = float(s.commit_at_client[i])
+                    self.on_commit(int(s.cid[i]), int(s.rid[i]))
+                self._now = t_save
 
     def _run_epoch_batches(self, epoch_end: float, leader: int,
                            dies_at: Optional[np.ndarray] = None) -> None:
         """Flush pending work due by ``epoch_end``; commit-triggered
         resubmissions landing inside the epoch run as further generations."""
         while True:
-            due = self._pending.pop_due(epoch_end)
+            with obs.span("loop.pending"):
+                due = self._pending.pop_due(epoch_end)
             if due.size == 0:
                 return
             s = self.engine.run_epoch(due, self._alive, leader,

@@ -65,6 +65,7 @@ from typing import Any, Optional, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core.clock import ClockParams
 from repro.sim.network import WAN_PARAMS, NetworkParams
 from repro.sim.workload import Workload, WorkloadDriver
@@ -1193,13 +1194,16 @@ def run_scenario_on_cluster(protocol_name: str,
     """`run_scenario`, additionally returning the driven cluster -- for
     callers that inspect post-run state (`repro.sim.trace` records the
     commit trace from it)."""
-    cluster, sc, skipped = make_scenario_cluster(
-        protocol_name, scenario, tier=tier, config=config, **kw)
+    obs.count("scenarios")
+    with obs.span("scenario.build"):
+        cluster, sc, skipped = make_scenario_cluster(
+            protocol_name, scenario, tier=tier, config=config, **kw)
     summary = WorkloadDriver(sc.workload).run(cluster)
     n_faults = len(sc.faults)
-    result = ScenarioResult.from_summary(
-        sc, summary, applied_faults=n_faults - len(skipped),
-        skipped_faults=len(skipped))
+    with obs.span("scenario.result"):
+        result = ScenarioResult.from_summary(
+            sc, summary, applied_faults=n_faults - len(skipped),
+            skipped_faults=len(skipped))
     return result, cluster
 
 

@@ -30,6 +30,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.hashing import key_group_np
 from repro.core.messages import OpType
 
@@ -213,13 +214,14 @@ class WorkloadDriver:
         in benchmarks/figs.py) inject here and drive `run_for` themselves."""
         w = self.workload
         rng = np.random.default_rng(w.seed)
-        for cid in range(cluster.n_clients):
-            t = w.warmup
-            while t < w.duration:
-                t += rng.exponential(1.0 / w.rate_per_client)
-                key, op = self._next_op(rng)
-                cluster.submit_at(t, cid, keys=self._next_keys(rng, key),
-                                  op=op)
+        with obs.span("inject"):
+            for cid in range(cluster.n_clients):
+                t = w.warmup
+                while t < w.duration:
+                    t += rng.exponential(1.0 / w.rate_per_client)
+                    key, op = self._next_op(rng)
+                    cluster.submit_at(t, cid, keys=self._next_keys(rng, key),
+                                      op=op)
 
     def run(self, cluster) -> dict:
         w = self.workload
@@ -227,7 +229,8 @@ class WorkloadDriver:
         if w.mode == "open":
             self.inject_open_loop(cluster)
             cluster.run_for(w.duration + w.drain)
-            s = cluster.summary()
+            with obs.span("scenario.result"):
+                s = cluster.summary()
             s["throughput"] = s["committed"] / max(w.duration - w.warmup, 1e-9)
             s["offered"] = w.rate_per_client * cluster.n_clients
         elif w.mode == "closed":
@@ -248,7 +251,8 @@ class WorkloadDriver:
                     key, op = self._next_op(rng)
                     cluster.submit(cid, keys=(key,), op=op)
             cluster.run_for(w.duration + w.drain)
-            s = cluster.summary()
+            with obs.span("scenario.result"):
+                s = cluster.summary()
             s["throughput"] = s["committed"] / w.duration
             s["n_clients"] = cluster.n_clients
         else:
