@@ -23,11 +23,15 @@ Phases:
 Each phase prints one JSON line: committed count, wall and compile
 seconds, the epoch programs traced (`repro.obs`'s ``programs_traced``
 events) with their pow2 batch buckets and the trace, lower and
-compile-or-load seconds of the dispatch that traced them, and whether
-the float64 outputs (log deadlines, client commit times) matched numpy bit
-for bit, with their largest difference in seconds (on the TPU float64 is a
-pair of float32 words, so they need not; the committed log must).  The
-last line is ``{"ok": true, "device": {...}}``.
+compile-or-load seconds of the dispatch that traced them, how many of
+its programs came from an earlier phase (``programs_shared``: the epoch
+programs are shared by every cluster of a tier class in the process, so
+a phase traces only the programs and buckets no earlier phase ran, and
+says so where that is none), and whether the float64 outputs (log
+deadlines, client commit times) matched numpy bit for bit, with their
+largest difference in seconds (on the TPU float64 is a pair of float32
+words, so they need not; the committed log must).  The last line is
+``{"ok": true, "device": {...}}``.
 """
 from __future__ import annotations
 
@@ -159,6 +163,8 @@ def run_phase(label: str, tier: str, scenario, compiles: CompileSpans):
     # deliveries pair up where they come in the same order
     deliveries_equal = np.array_equal(trace.commit_uids, ref_trace.commit_uids)
     programs = program_rows(rec, spans)
+    shared = {k.split(".", 1)[1]: v for k, v in rec["counters"].items()
+              if k.startswith("programs_shared.")}
     report = {
         "phase": label, "tier": tier, "scenario": scenario.name,
         "n_requests": res.n_requests, "committed": res.committed,
@@ -175,7 +181,12 @@ def run_phase(label: str, tier: str, scenario, compiles: CompileSpans):
                               if k == "compile_s"]),
         "programs": programs,
         "buckets": sorted({p["bucket"] for p in programs}),
+        "programs_shared": shared,
     }
+    if not programs:
+        report["programs_note"] = (
+            "every epoch program and batch bucket was traced by an earlier "
+            "phase of this process; none was traced or compiled here")
     return report, cluster
 
 

@@ -224,7 +224,14 @@ def _pow2_bucket(n: int) -> int:
 
 
 class ComputeTier:
-    """Backend for the DOM hot loops; see module docstring for the tiers."""
+    """Backend for the DOM hot loops; see module docstring for the tiers.
+
+    The fused epoch programs (`epoch_step`, `epoch_scan`) are shared by
+    every instance of a tier class in the process (`_EPOCH_PROGRAMS`), so
+    `admit_traced` and `order_traced` must not read instance state: a
+    program traced through one instance runs for all of them.  A tier whose
+    `shares_programs()` is false keeps its programs per instance.
+    """
 
     name = "abstract"
     # Pad epoch batches to pow2 buckets before release_schedule? True for
@@ -256,19 +263,37 @@ class ComputeTier:
 
     def epoch_step(self, f: int, use_kcls: bool, use_cap: bool = False):
         """The fused stamp->dom->commit program (jitted, cached per shape)."""
-        cache = self.__dict__.setdefault("_fused_cache", {})
-        key = (f, use_kcls, use_cap)
-        if key not in cache:
-            cache[key] = _build_fused_step(self, f, use_kcls, use_cap)
-        return cache[key]
+        return self._epoch_program("step", f, use_kcls, use_cap)
 
     def epoch_scan(self, f: int, use_kcls: bool, use_cap: bool = False):
         """The K-epochs-per-dispatch `lax.scan` program (fault-free path)."""
-        cache = self.__dict__.setdefault("_scan_cache", {})
-        key = (f, use_kcls, use_cap)
-        if key not in cache:
-            cache[key] = _build_fused_scan(self, f, use_kcls, use_cap)
-        return cache[key]
+        return self._epoch_program("scan", f, use_kcls, use_cap)
+
+    def _epoch_program(self, kind: str, f: int, use_kcls: bool,
+                       use_cap: bool):
+        # The instance memo keeps the per-epoch lookup to one dict get and
+        # counts each key once per instance (one tier per cluster).
+        memo = self.__dict__.setdefault("_programs", {})
+        key = (kind, type(self), f, use_kcls, use_cap)
+        program = memo.get(key)
+        if program is None:
+            shared = _EPOCH_PROGRAMS if self.shares_programs() else {}
+            program = shared.get(key)
+            if program is None:
+                obs.count("programs_shared.miss")
+                build = _build_fused_step if kind == "step" \
+                    else _build_fused_scan
+                program = shared[key] = build(self, f, use_kcls, use_cap)
+            else:
+                obs.count("programs_shared.hit")
+            memo[key] = program
+        return program
+
+    def shares_programs(self) -> bool:
+        """Whether this tier's epoch programs go in the process-wide
+        registry (and so outlive the tier with every executable they
+        compiled)."""
+        return True
 
 
 class NumpyTier(ComputeTier):
@@ -337,6 +362,16 @@ class PallasTier(JitTier):
         adm = dom_admit(d, a, use_pallas=True)
         rel = np.where(adm, np.maximum(d[:, None], a), np.inf)
         return adm, rel
+
+    def shares_programs(self) -> bool:
+        # Interpreted on the CPU, each compiled shape of a kernel program
+        # holds thousands of memory mappings; kept for the life of the
+        # process across a catalog of scenarios they exceed the kernel's
+        # mapping limit and XLA's compiler aborts.  Compiled for the TPU
+        # they are small, and shared like the jit tier's.
+        from repro.kernels.ops import interpret_mode
+
+        return not interpret_mode()
 
     def deadline_order(self, deadlines):
         from repro.kernels.ops import dom_deadline_order
@@ -845,6 +880,20 @@ def _build_fused_scan(tier: ComputeTier, f: int, use_kcls: bool,
 
     donate = (0, 1, 2) if jax.default_backend() != "cpu" else ()
     return jax.jit(scan_fn, donate_argnums=donate)
+
+
+# The process's fused epoch programs, keyed by (kind, tier class, f,
+# use_kcls, use_cap): every cluster of a process dispatches the same jitted
+# objects, so JAX's in-memory caches (traces, executables) outlive a
+# cluster and a scenario run after the first traces nothing it ran before.
+# Operand shapes key each program's own cache; donation is per call.
+_EPOCH_PROGRAMS: dict[tuple, object] = {}
+
+
+def clear_epoch_programs() -> None:
+    """Forget the shared epoch programs, so the next tier builds and traces
+    afresh (for tests that observe a first trace)."""
+    _EPOCH_PROGRAMS.clear()
 
 
 # ---------------------------------------------------------------------------

@@ -21,9 +21,10 @@ finished `EpochState` -- the runtime complement to the static linter
     must never re-derive it, or the cross-group atomic-order guarantee dies.
 
 The wrapper is PURE delegation -- every compute call goes to the inner tier
-untouched, `name` reports the inner tier's name, and the fused-step cache
-lives on the inner tier -- so a sanitized run is bit-for-bit identical to an
-unwrapped one (asserted by tests/test_sanitizer.py).
+untouched, `name` reports the inner tier's name, and the fused epoch
+programs are the inner tier's (shared with every bare tier of its class)
+-- so a sanitized run is bit-for-bit identical to an unwrapped one
+(asserted by tests/test_sanitizer.py).
 
 Enable via `VectorizedConfig(sanitize=True)` or the ``REPRO_SANITIZE=1``
 environment variable; the CI recovery smoke runs with it on.

@@ -4,6 +4,7 @@ sampling)."""
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import CommonConfig, make_cluster
 from repro.core.engine import (
     PENDING_DTYPE,
@@ -447,8 +448,9 @@ def _k_dispatch_cluster(name, k, crash=None):
         cl.submit_at(float(t), i % 3, keys=(i % 5,))
     if crash is not None:
         cl.crash_at(crash, 0)
-    cl.run_for(0.3)
-    return cl
+    with obs.recording() as rec:
+        cl.run_for(0.3)
+    return cl, rec["counters"].get("epochs.scan", 0)
 
 
 def _assert_bitwise_equal_runs(cl_a, cl_b):
@@ -474,12 +476,12 @@ def test_k_scan_dispatch_is_bitwise_identical_to_per_epoch_jit():
     `lax.scan`) is bit-for-bit identical to the sequential per-epoch fused
     path on a fault-free run -- same commits, latencies, leaders, OWD
     pool, and committed sequence."""
-    base = _k_dispatch_cluster("nezha-vectorized-jit", 1)
-    scan = _k_dispatch_cluster("nezha-vectorized-jit", 64)
-    # the fast path actually ran: the K=1 run never compiles a scan
-    # program, the K=64 run does
-    assert not getattr(base.engine.tier, "_scan_cache", {})
-    assert getattr(scan.engine.tier, "_scan_cache", {})
+    base, base_scanned = _k_dispatch_cluster("nezha-vectorized-jit", 1)
+    scan, scanned = _k_dispatch_cluster("nezha-vectorized-jit", 64)
+    # the fast path actually ran: the K=1 run never dispatches a scan
+    # window, the K=64 run does
+    assert base_scanned == 0
+    assert scanned > 0
     _assert_bitwise_equal_runs(base, scan)
 
 
@@ -487,10 +489,11 @@ def test_k_scan_crash_segments_and_stays_bitwise_identical():
     """Fault boundaries segment the scan: a leader crash mid-run forces
     the per-epoch path through detection + view change, and the K>1 run
     still equals K=1 bitwise (recovery included)."""
-    base = _k_dispatch_cluster("nezha-vectorized-jit", 1, crash=0.05)
-    scan = _k_dispatch_cluster("nezha-vectorized-jit", 64, crash=0.05)
+    base, _ = _k_dispatch_cluster("nezha-vectorized-jit", 1, crash=0.05)
+    scan, scanned = _k_dispatch_cluster("nezha-vectorized-jit", 64,
+                                        crash=0.05)
     assert scan.summary()["view_changes"] == 1      # recovery exercised
-    assert getattr(scan.engine.tier, "_scan_cache", {})
+    assert scanned > 0
     _assert_bitwise_equal_runs(base, scan)
 
 
@@ -498,10 +501,10 @@ def test_k_scan_crash_segments_and_stays_bitwise_identical():
 def test_k_scan_dispatch_parity_pallas():
     """The scan fast path composes with the Pallas kernels: K=64 pallas ==
     K=1 pallas == K=1 jit, bitwise."""
-    jit1 = _k_dispatch_cluster("nezha-vectorized-jit", 1)
-    pal1 = _k_dispatch_cluster("nezha-vectorized-pallas", 1)
-    pal64 = _k_dispatch_cluster("nezha-vectorized-pallas", 64)
-    assert getattr(pal64.engine.tier, "_scan_cache", {})
+    jit1, _ = _k_dispatch_cluster("nezha-vectorized-jit", 1)
+    pal1, _ = _k_dispatch_cluster("nezha-vectorized-pallas", 1)
+    pal64, scanned = _k_dispatch_cluster("nezha-vectorized-pallas", 64)
+    assert scanned > 0
     _assert_bitwise_equal_runs(pal1, pal64)
     np.testing.assert_array_equal(np.concatenate(jit1._latencies),
                                   np.concatenate(pal64._latencies))

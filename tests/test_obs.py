@@ -254,6 +254,10 @@ def test_programs_traced_match_jax_trace_events():
     import jax
     from jax._src.dispatch import JAXPR_TRACE_EVENT
 
+    from repro.core.engine import clear_epoch_programs
+
+    # a first trace of each program, whatever ran earlier in this process
+    clear_epoch_programs()
     seen = []
 
     def on_span(event, start, end, **kw):
