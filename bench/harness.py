@@ -295,6 +295,7 @@ class Session:
         self.watch = probes.CompileWatch()
         self.recorder = probes.Recorder()
         self.spans = probes.Spans() if trace else None
+        self.program: Optional[dict] = None     # repro.obs's record
         self._stack = None
 
     def __enter__(self):
@@ -329,26 +330,36 @@ class Session:
 
     def window(self, seed: int, seconds: float):
         """Repetitions back to back until ``seconds`` have passed; the last
-        runs to its end.  Returns (reps, start, end, cache misses, trace)."""
+        runs to its end.  Returns (reps, start, end, cache misses, trace).
+
+        A traced window records the program's own spans and counters
+        (`repro.obs`) into ``self.program``; an untraced one leaves
+        recording off, so its path is the one users run."""
+        from repro import obs
+
         misses_before = self.watch.cache_misses
         reps: list[Rep] = []
         profile = None
-        w0 = time.time()
-        while not reps or time.time() - w0 < seconds:
-            i = len(reps)
-            scenario = make_scenario(self.cell, seed, i)
-            self.recorder.begin()
-            tracer = (probes.DeviceTrace() if self.trace and i == 0
-                      else contextlib.nullcontext())
-            with tracer:
-                r0 = time.time()
-                result, cluster = run_repetition(self.cell, scenario)
-                r1 = time.time()
-            if self.trace and i == 0:
-                profile = tracer
-            reps.append(Rep(index=i, seed=rep_seeds(seed, i)[1], t0=r0, t1=r1,
-                            result=result, records=self.recorder.take(),
-                            cluster=cluster))
+        recording = obs.recording() if self.trace \
+            else contextlib.nullcontext()
+        with recording as self.program:
+            w0 = time.time()
+            while not reps or time.time() - w0 < seconds:
+                i = len(reps)
+                scenario = make_scenario(self.cell, seed, i)
+                self.recorder.begin()
+                tracer = (probes.DeviceTrace() if self.trace and i == 0
+                          else contextlib.nullcontext())
+                with tracer:
+                    r0 = time.time()
+                    result, cluster = run_repetition(self.cell, scenario)
+                    r1 = time.time()
+                if self.trace and i == 0:
+                    profile = tracer
+                reps.append(Rep(index=i, seed=rep_seeds(seed, i)[1], t0=r0,
+                                t1=r1, result=result,
+                                records=self.recorder.take(),
+                                cluster=cluster))
         return (reps, w0, reps[-1].t1, self.watch.cache_misses - misses_before,
                 profile)
 
@@ -375,7 +386,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     attempted = sum(r.result.n_requests for r in reps)
     device = {**device_info(), "memory_peak_bytes": peak}
     if trace:
-        metrics = per_layer_metrics(cell, session, w0, w1, profile, device)
+        ctx = traced_context(session, reps, w0, w1, profile)
+        if ctx.device is not None:
+            device["busy_s"] = ctx.device["busy_s"]
+            device["window_s"] = ctx.device["window_s"]
+        metrics = per_layer_metrics(cell, ctx, session.root)
     else:
         metrics = {"committed_per_s": {"value": committed / span_s,
                                        "unit": "req/s"},
@@ -520,43 +535,86 @@ def check_reps(cell: Cell, reps: list, ft=None) -> dict:
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
 class Context:
-    """What a metric reader reads: host spans and counters over the traced
-    window, and the device trace of one repetition in it."""
+    """What a metric reader reads over the traced window.
+
+      spans, counters    the benchmark's own wrappers (`bench/probes.py`)
+                         and JAX's compile spans (``compile``);
+      program_spans,     the program's own record (`repro.obs`), kept
+      program_counters   apart; empty where nothing was recorded;
+      device             from the profiler trace of repetition 0: busy_s,
+                         window_s, ops (every operation's device seconds by
+                         its trace name), kind (the device kind) and peaks
+                         (its entry in bench/peaks.json); None untraced;
+      reps               the window's repetitions;
+      epochs             each epoch of repetition 0: {"n": requests (not
+                         padded), "R": receivers}.
+    """
 
     window: tuple               # (t0, t1) wall seconds
     spans: dict                 # name -> [(t0, t1)] wall seconds
     counters: dict              # name -> int
-    device: Optional[dict]      # {"busy_s", "window_s"} from the trace
+    device: Optional[dict]
+    program_spans: dict = dataclasses.field(default_factory=dict)
+    program_counters: dict = dataclasses.field(default_factory=dict)
+    reps: int = 0
+    epochs: list = dataclasses.field(default_factory=list)
 
     @property
     def window_s(self) -> float:
         return self.window[1] - self.window[0]
 
-    def share(self, *names: str) -> Optional[float]:
-        """Percent of the window inside the union of the named spans, or
-        None where no such span was recorded."""
-        iv = [s for n in names for s in self.spans.get(n, [])]
+    def _share(self, spans: dict, names) -> Optional[float]:
+        iv = [s for n in names for s in spans.get(n, [])]
         if not iv:
             return None
         return 100.0 * probes.union_length(iv, *self.window) / self.window_s
 
+    def share(self, *names: str) -> Optional[float]:
+        """Percent of the window inside the union of the named spans, or
+        None where no such span was recorded."""
+        return self._share(self.spans, names)
 
-def per_layer_metrics(cell: Cell, session: Session, w0: float, w1: float,
-                      profile, device: dict) -> dict:
-    all_spans = dict(session.spans.spans)
-    all_spans["compile"] = list(session.watch.spans)
+    def program_share(self, *names: str) -> Optional[float]:
+        """`share` over the program's own spans."""
+        return self._share(self.program_spans, names)
+
+
+def epoch_shapes(records: list) -> list:
+    """Requests and receivers of each epoch record of one repetition."""
+    return [{"n": int(rec["t"].size), "R": int(rec["alive"].size)}
+            for kind, rec, _ in records if kind == "epoch"]
+
+
+def traced_context(session: Session, reps: list, w0: float, w1: float,
+                   profile) -> Context:
+    """The Context of a traced window.  The device trace's idle time is
+    painted by the benchmark's spans and the program's, innermost first."""
+    spans = dict(session.spans.spans)
+    spans["compile"] = list(session.watch.spans)
+    program = session.program or {"spans": {}, "counters": {}}
+    painted = {k: list(v) for k, v in spans.items()}
+    for k, v in program["spans"].items():
+        painted.setdefault(k, []).extend(v)
     dev = None
     if profile is not None:
-        profile.reduce(all_spans)
-    if profile is not None and profile.busy_s is not None:
-        dev = {"busy_s": profile.busy_s, "window_s": profile.window_s}
-        device["busy_s"] = profile.busy_s
-        device["window_s"] = profile.window_s
-    ctx = Context(window=(w0, w1), spans=all_spans,
-                  counters=dict(session.recorder.counters), device=dev)
+        profile.reduce(painted)
+        if profile.busy_s is not None:
+            kind = device_info()["kind"]
+            dev = {"busy_s": profile.busy_s, "window_s": profile.window_s,
+                   "ops": profile.ops, "kind": kind, "peaks": peaks(kind)}
+    return Context(window=(w0, w1), spans=spans,
+                   counters=dict(session.recorder.counters), device=dev,
+                   program_spans=dict(program["spans"]),
+                   program_counters=dict(program["counters"]),
+                   reps=len(reps),
+                   epochs=epoch_shapes(reps[0].records) if reps else [])
+
+
+def per_layer_metrics(cell: Cell, ctx: Context, root: Path = ROOT) -> dict:
+    """Each of the cell's per-layer metrics its reader finds."""
     out = {}
     for m in cell.per_layer:
-        value = metric_reader(m["name"], session.root)(ctx)
+        value = metric_reader(m["name"], root)(ctx)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out

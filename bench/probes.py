@@ -12,6 +12,9 @@
                    warm-up can compile it at every batch bucket;
   DeviceTrace      traced runs only: a profiler trace of one repetition.
 
+The program's own spans and counters (`repro.obs`) are recorded by the
+harness in traced windows, not here.
+
 Each installs by replacing a method on its class and puts the original
 back on exit.
 """
@@ -297,6 +300,7 @@ class DeviceTrace:
         self.wall: tuple = (0.0, 0.0)
         self.busy_s: Optional[float] = None
         self.window_s: Optional[float] = None
+        self.ops: dict = {}
         self.breakdown: Optional[dict] = None
 
     def __enter__(self):
@@ -321,7 +325,8 @@ class DeviceTrace:
         return False
 
     def reduce(self, spans: dict) -> None:
-        """Busy time, top device ops and idle time by host activity."""
+        """Busy time, every device op's time, the top ones, and idle time
+        by host activity (``spans``: label -> [(t0, t1)] wall seconds)."""
         from pathlib import Path
 
         from bench import trace as tr
@@ -337,6 +342,7 @@ class DeviceTrace:
             if red is not None:
                 self.busy_s = red["busy_s"]
                 self.window_s = red["window_s"]
+                self.ops = red["ops"]
                 self.breakdown = {"device_ops": red["device_ops"],
                                   "idle_gaps": red["idle_gaps"]}
         finally:

@@ -8,10 +8,15 @@ window and maps the host's wall clock (time.time) onto the trace's clock.
   busy_s      the union of operation intervals inside the window, averaged
               over the device planes;
   window_s    the marker's length;
-  device_ops  the 10 operation names with the most device time;
+  ops         every operation's device seconds inside the window, summed
+              over the device planes, keyed by the trace's name for it, uncut;
+  device_ops  the 10 operation names with the most device time, their
+              layouts removed and cut to NAME_CHARS;
   idle_gaps   device idle time inside the window by what the host was doing:
               the innermost host span open at each instant (``other`` where
-              none was), the 10 largest.
+              none was), the 10 largest.  The spans are the benchmark's
+              wrappers, JAX's compile spans and the program's own spans
+              (`repro.obs`), which nest inside the wrappers.
 """
 from __future__ import annotations
 
@@ -75,7 +80,7 @@ def reduce(data, mark: str, wall: tuple, spans: dict) -> Optional[dict]:
     t0, t1 = float(ev.start_ns), float(ev.end_ns)
     offset = t0 - wall[0] * 1e9            # trace ns = wall s * 1e9 + offset
     busy_each = []
-    op_time: dict = {}
+    ops: dict = {}
     busy_all: list = []
     for plane in planes:
         line = ops_line(plane)
@@ -86,17 +91,20 @@ def reduce(data, mark: str, wall: tuple, spans: dict) -> Optional[dict]:
             a, b = max(float(e.start_ns), t0), min(float(e.end_ns), t1)
             if b > a:
                 iv.append((a, b))
-                name = op_name(e.name)
-                op_time[name] = op_time.get(name, 0.0) + (b - a) * 1e-9
+                ops[e.name] = ops.get(e.name, 0.0) + (b - a) * 1e-9
         merged = merge(iv)
         busy_each.append(sum(b - a for a, b in merged) * 1e-9)
         busy_all.extend(merged)
     if not busy_each:
         return None
     window_s = (t1 - t0) * 1e-9
-    device_ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:TOP]
+    by_short: dict = {}
+    for name, s in ops.items():
+        short = op_name(name)
+        by_short[short] = by_short.get(short, 0.0) + s
+    device_ops = sorted(by_short.items(), key=lambda kv: -kv[1])[:TOP]
     return {"busy_s": float(np.mean(busy_each)), "window_s": window_s,
-            "device_ops": [[k, v] for k, v in device_ops],
+            "ops": ops, "device_ops": [[k, v] for k, v in device_ops],
             "idle_gaps": idle_by_host(merge(busy_all), spans, t0, t1, offset)}
 
 
