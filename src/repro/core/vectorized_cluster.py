@@ -654,7 +654,9 @@ class VectorizedNezhaCluster(Cluster):
                 k_scan, fence = self._scan_window(horizon)
                 if k_scan:
                     obs.count("epochs.scan", k_scan)
-                    self._run_scan_window(k_scan)
+                    obs.count("scan.windows")
+                    with obs.span("loop.scan"):
+                        self._run_scan_window(k_scan)
                     continue
                 obs.count("epochs.step")
                 obs.count("scan_fence." + fence)

@@ -192,6 +192,133 @@ def test_a_fence_that_holds_all_run_blocks_every_step(recorded, name,
     assert "epochs.scan" not in c
 
 
+# -- the K-epoch scan windows ------------------------------------------------
+def _spy_scan_windows(monkeypatch) -> list:
+    """The K of each `_run_scan_window` call, in order."""
+    from repro.core.vectorized_cluster import VectorizedNezhaCluster
+
+    ks = []
+    orig = VectorizedNezhaCluster._run_scan_window
+
+    def spy(self, k):
+        ks.append(k)
+        return orig(self, k)
+
+    monkeypatch.setattr(VectorizedNezhaCluster, "_run_scan_window", spy)
+    return ks
+
+
+def test_scan_windows_are_spanned_and_counted(monkeypatch):
+    ks = _spy_scan_windows(monkeypatch)
+    with obs.recording() as rec:
+        run(replace(STEADY, seed=23))
+    c, spans = rec["counters"], rec["spans"]
+    assert ks
+    assert c["scan.windows"] == len(ks) == len(spans["loop.scan"])
+    assert sum(ks) == c["epochs.scan"]
+    # a dispatch outside every `fused` step span ran on the scan path, and
+    # lies inside its window's span
+    fused = spans["fused"]
+    scan_dispatch = [(a, b) for a, b in spans["fused.dispatch"]
+                     if not any(f0 <= a and b <= f1 for f0, f1 in fused)]
+    assert scan_dispatch
+    for a, b in scan_dispatch:
+        assert any(s0 <= a and b <= s1 for s0, s1 in spans["loop.scan"])
+
+
+def test_each_dispatch_opens_one_pull(monkeypatch):
+    """`epochs_per_pull` counts pulls as `fused.pull` spans: one per
+    `run_epoch` step and one per scan window."""
+    from repro.core.engine import DomEngine
+
+    ks = _spy_scan_windows(monkeypatch)
+    steps = []
+    orig = DomEngine.run_epoch
+
+    def spy(self, *args, **kw):
+        steps.append(True)
+        return orig(self, *args, **kw)
+
+    monkeypatch.setattr(DomEngine, "run_epoch", spy)
+    with obs.recording() as rec:
+        run(replace(STEADY, seed=23))
+    assert ks and steps
+    assert len(rec["spans"]["fused.pull"]) == len(ks) + len(steps)
+
+
+def test_scan_window_span_and_counter_are_inert_when_off(monkeypatch):
+    ks = _spy_scan_windows(monkeypatch)
+    seen = []
+    span, count = obs.span, obs.count
+
+    def spy_span(name):
+        out = span(name)
+        seen.append((name, out))
+        return out
+
+    def spy_count(name, n=1):
+        seen.append((name, obs._record))
+        count(name, n)
+
+    monkeypatch.setattr(obs, "span", spy_span)
+    monkeypatch.setattr(obs, "count", spy_count)
+    run(replace(STEADY, seed=23))
+    assert ks and obs._record is None
+    scan = [out for name, out in seen if name == "loop.scan"]
+    windows = [out for name, out in seen if name == "scan.windows"]
+    assert len(scan) == len(windows) == len(ks)
+    assert all(out is obs._OFF for out in scan)
+    assert all(out is None for out in windows)
+
+
+def _reader(name):
+    from bench import harness
+
+    return harness.metric_reader(name, Path(__file__).resolve().parents[1])
+
+
+def _ctx(program_spans=None, program_counters=None, counters=None):
+    from bench import harness
+
+    return harness.Context(window=(0.0, 10.0), spans={},
+                           counters=counters or {}, device=None,
+                           program_spans=program_spans or {},
+                           program_counters=program_counters or {})
+
+
+def _pulls(n):
+    return {"fused.pull": [(0.1 * i, 0.1 * i + 0.05) for i in range(n)]}
+
+
+@pytest.mark.parametrize("work,pulls,expected", [
+    (64, 6, 64 / 6),        # every work epoch in six K=16 windows
+    (50, 50, 1.0),          # every epoch stepped
+    (64, 4, 16.0),
+    (70, 10, 7.0)])         # six windows and four stepped work epochs
+def test_epochs_per_pull_on_a_synthetic_record(work, pulls, expected):
+    ctx = _ctx(program_spans=_pulls(pulls),
+               counters={"work_epochs": work, "scan_epochs": 0})
+    assert _reader("epochs_per_pull")(ctx) == pytest.approx(expected)
+
+
+def test_scan_window_share_on_a_synthetic_record():
+    spans = {"loop.scan": [(0.0, 4.0), (3.0, 5.0), (9.0, 11.0)],
+             "fused.pull": [(1.0, 2.0)]}
+    assert _reader("scan_window_share")(_ctx(program_spans=spans)) \
+        == pytest.approx(60.0)
+
+
+@pytest.mark.parametrize("name,ctx", [
+    ("scan_window_share", _ctx()),
+    ("scan_window_share", _ctx(program_spans={"loop.pending": [(0, 1)]})),
+    ("epochs_per_pull", _ctx()),
+    # work epochs counted by the benchmark, but no program record
+    ("epochs_per_pull", _ctx(counters={"work_epochs": 64,
+                                       "scan_epochs": 64}))])
+def test_scan_readers_without_a_record_return_none(name, ctx):
+    assert _reader(name)(ctx) is None
+
+
 # -- the fence of one window, constructed ----------------------------------
 def _cluster(tier="jit", environment="gcp-intra-zone", **over):
     sc = Scenario("obs-unit", environment=environment, workload=SHORT,

@@ -104,3 +104,17 @@ def test_fused_pallas_step_calls_the_kernels_on_v5e(one_chip, monkeypatch):
     with jax.enable_x64(True):
         text = step.lower(**args).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
+
+
+def test_fused_jit_scan_compiles_for_v5e_within_a_minute(one_chip):
+    """The K=16 epoch scan with key classes at the WAN deployment's pool
+    (DomParams.window 200): the program `run_epoch_window` dispatches."""
+    from repro.analysis.lint.trace_safety import _fused_scan_args
+    from repro.core.engine import JitTier
+
+    scan = JitTier().epoch_scan(1, use_kcls=True)
+    args = _shapes(_fused_scan_args(16, N, R, window=200), one_chip)
+    t0 = time.perf_counter()
+    with jax.enable_x64(True):
+        scan.lower(**args).compile()
+    assert time.perf_counter() - t0 < 60.0
